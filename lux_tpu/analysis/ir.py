@@ -133,7 +133,7 @@ def trace_target(target: TraceTarget):
 # -- jaxpr walking ------------------------------------------------------
 
 def _as_jaxprs(v) -> List:
-    from jax import core as jcore
+    from jax.extend import core as jcore
 
     if isinstance(v, jcore.ClosedJaxpr):
         return [v.jaxpr]
@@ -593,18 +593,18 @@ def _lit_scalar(v) -> Optional[float]:
 
 
 # Primitives through which a known scalar constant keeps its value
-# (shape/dtype bookkeeping only — dtype conversion of +-inf and the
-# integer identities is exact for the cases LUX405 compares).
+# (shape/dtype/varying-axes bookkeeping only — dtype conversion of +-inf
+# and the integer identities is exact for the cases LUX405 compares).
 _VALUE_PRESERVING_PRIMS = (
     "broadcast_in_dim", "reshape", "convert_element_type", "squeeze",
-    "expand_dims", "copy", "slice",
+    "expand_dims", "copy", "slice", "pvary",
 )
 
 
 def _closed_subs(v) -> List[Tuple[object, tuple]]:
     """(jaxpr, consts) pairs for sub-jaxprs, keeping ClosedJaxpr consts
     paired with their constvars (``_as_jaxprs`` drops them)."""
-    from jax import core as jcore
+    from jax.extend import core as jcore
 
     if isinstance(v, jcore.ClosedJaxpr):
         return [(v.jaxpr, tuple(v.consts))]

@@ -169,10 +169,9 @@ def _mib(n: float) -> str:
 
 
 def _is_literal(v) -> bool:
-    from jax import core as jcore
+    from jax.extend import core as jcore
 
-    lit = getattr(jcore, "Literal", None)
-    return lit is not None and isinstance(v, lit)
+    return isinstance(v, jcore.Literal)
 
 
 def _eqn_subjaxprs(eqn) -> List:

@@ -6,7 +6,7 @@ code previously only promised in prose:
 - LUX001 host-sync-in-hot-loop: Gunrock-style frontier/iteration loops
   are fast only while no hidden host round-trip sits inside them (a
   single ``.item()`` per iteration serializes the whole async dispatch
-  pipeline — PERF.md measured 620 vs 316 ms/iter for dispatch-per-step
+  pipeline — PERF_NOTES.md measured 620 vs 316 ms/iter for dispatch-per-step
   vs fused).
 - LUX002 recompile-hygiene: jitted steps must donate their buffer
   argument (else HBM holds two copies) and jitted callables must not be

@@ -61,7 +61,7 @@ from lux_tpu.obs import (
 from lux_tpu.ops.segment import identity_for, segment_reduce
 from lux_tpu.parallel.mesh import PARTS_AXIS, make_mesh, parts_sharding
 from lux_tpu.parallel.shard import ShardedGraph, resolve_exchange
-from lux_tpu.utils import compat, flags
+from lux_tpu.utils import flags
 from lux_tpu.utils.logging import get_logger
 from lux_tpu.utils.timing import Timer
 
@@ -192,7 +192,7 @@ class ShardedAdaptiveExecutor:
         self.exchange_downgrades = 0
         state_spec = GasState(P(PARTS_AXIS), P(PARTS_AXIS), P(PARTS_AXIS))
         self._state_spec = state_spec
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             self._shard_step,
             mesh=self.mesh,
             in_specs=(state_spec, self._specs),
@@ -552,13 +552,13 @@ class ShardedAdaptiveExecutor:
             return new_state, jax.lax.psum(cnt_local, PARTS_AXIS), flag
 
         st, counts, flags_, done, last = _chunk_while(
-            one_iter, state, k, limit[0]
+            one_iter, state, k, limit[0], flag_axes=(PARTS_AXIS,)
         )
         return st, counts[None], flags_[None], done[None], last[None]
 
     def _multi(self, state: GasState, limit: int, k: int):
         if k not in self._chunk_cache:
-            mapped = compat.shard_map(
+            mapped = jax.shard_map(
                 lambda st, dg, lim: self._shard_chunk(st, dg, lim, k),
                 mesh=self.mesh,
                 in_specs=(self._state_spec, self._specs, P()),
@@ -703,7 +703,7 @@ class ShardedAdaptiveExecutor:
         def sm(fn, in_specs, out_specs):
             # check_vma off: all_gather outputs are replicated by
             # construction but the static checker cannot infer it here.
-            return jax.jit(compat.shard_map(
+            return jax.jit(jax.shard_map(
                 fn, mesh=self.mesh, in_specs=in_specs,
                 out_specs=out_specs, check_vma=False,
             ))
@@ -1026,7 +1026,7 @@ class ShardedMultiSourceGasExecutor:
         self.exchange_downgrades = 0
         state_spec = GasState(P(PARTS_AXIS), P(PARTS_AXIS), P(PARTS_AXIS))
         self._state_spec = state_spec
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             self._shard_step,
             mesh=self.mesh,
             in_specs=(state_spec, self._specs),
@@ -1116,13 +1116,13 @@ class ShardedMultiSourceGasExecutor:
             )
 
         st, counts, flags_, done, last = _chunk_while(
-            one_iter, state, k, limit[0]
+            one_iter, state, k, limit[0], flag_axes=(PARTS_AXIS,)
         )
         return st, counts[None], flags_[None], done[None], last[None]
 
     def _multi(self, state: GasState, limit: int, k: int):
         if k not in self._chunk_cache:
-            mapped = compat.shard_map(
+            mapped = jax.shard_map(
                 lambda st, dg, lim: self._shard_chunk(st, dg, lim, k),
                 mesh=self.mesh,
                 in_specs=(self._state_spec, self._specs, P()),

@@ -36,7 +36,6 @@ from lux_tpu.obs import (
     prof,
     recorder_for,
 )
-from lux_tpu.utils import compat
 from lux_tpu.utils.timing import Timer
 from lux_tpu.ops.segment import segment_reduce, segment_sum_by_rowptr
 from lux_tpu.parallel.mesh import PARTS_AXIS, make_mesh, parts_sharding
@@ -115,7 +114,7 @@ class ShardedPullExecutor:
         self._device_graph = sgd
 
         specs = {k: P(PARTS_AXIS) for k in sgd}
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             self._shard_step,
             mesh=self.mesh,
             in_specs=(P(PARTS_AXIS), specs),
@@ -290,7 +289,7 @@ class ShardedPullExecutor:
                 # check_vma off: the all-gathered flat table is
                 # replicated by construction, but the static checker
                 # cannot infer it here.
-                return jax.jit(compat.shard_map(
+                return jax.jit(jax.shard_map(
                     fn, mesh=self.mesh, in_specs=in_specs,
                     out_specs=out_specs, check_vma=False,
                 ))

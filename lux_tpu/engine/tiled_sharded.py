@@ -56,7 +56,6 @@ from lux_tpu.obs import (
     prof,
     recorder_for,
 )
-from lux_tpu.utils import compat
 from lux_tpu.utils.timing import Timer
 from lux_tpu.ops.tiled_spmv import (
     BLOCK,
@@ -276,7 +275,7 @@ class ShardedTiledExecutor:
         # lane_select_tail_sums are freshly-zeroed per-shard accumulators, which
         # the varying-manual-axes checker would otherwise insist on seeing
         # pvary-annotated at every scan site.
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             self._shard_step,
             mesh=self.mesh,
             in_specs=(P(PARTS_AXIS), specs, P()),
@@ -630,7 +629,7 @@ class ShardedTiledExecutor:
             specs = {k: P(PARTS_AXIS) for k in self._shard_args}
 
             def sm(fn, in_specs, out_specs):
-                return jax.jit(compat.shard_map(
+                return jax.jit(jax.shard_map(
                     fn, mesh=self.mesh, in_specs=in_specs,
                     out_specs=out_specs, check_vma=False,
                 ))

@@ -103,11 +103,13 @@ def setup_telemetry(args):
 
 
 def load_graph(path: str, program, log):
-    from lux_tpu.native import io as native_io
-    from lux_tpu.utils.platform import ensure_backend
+    import jax
 
-    platform = ensure_backend()
-    log.info("jax platform: %s", platform)
+    from lux_tpu.native import io as native_io
+    from lux_tpu.utils.platform import enable_compile_cache
+
+    log.info("jax platform: %s (compile cache %s)",
+             jax.devices()[0].platform, enable_compile_cache())
     with Timer() as t:
         g = native_io.read_lux(path)
     log.info("loaded %s: nv=%d ne=%d (%.2fs)", path, g.nv, g.ne, t.elapsed)
@@ -182,17 +184,18 @@ def make_executor(g, program, args, log=None):
 
     if isinstance(program, GasProgram):
         # The adaptive executor owns its direction choice (LUX_GAS pins
-        # it); layout/parts knobs belong to the legacy engines.
-        if args.parts > 1:
-            raise SystemExit(
-                f"error: {program.name} (a GAS app) is single-device for "
-                "now; drop -parts"
-            )
+        # it); the layout knob belongs to the legacy engines.
         if args.layout != "auto":
             raise SystemExit(
                 f"error: -layout {args.layout} has no effect on "
                 f"{program.name} (a GAS app); use LUX_GAS=pull|push|adaptive"
             )
+        if args.parts > 1:
+            from lux_tpu.engine.gas_sharded import ShardedAdaptiveExecutor
+            from lux_tpu.parallel.mesh import make_mesh
+
+            return ShardedAdaptiveExecutor(
+                g, program, mesh=make_mesh(args.parts))
         return AdaptiveExecutor(g, program)
     is_push = hasattr(program, "init_frontier")
     use_tiled = False

@@ -58,8 +58,8 @@ def reference_pagerank(graph, num_iters: int) -> np.ndarray:
     vals = np.where(deg == 0, rank, rank / np.maximum(deg, 1))
     dst = graph.col_dst
     for _ in range(num_iters):
-        acc = np.zeros(graph.nv, dtype=np.float64)
-        np.add.at(acc, dst, vals[graph.col_src])
+        acc = np.bincount(dst, weights=vals[graph.col_src],
+                          minlength=graph.nv)
         r = (1.0 - ALPHA) / graph.nv + ALPHA * acc
         vals = np.where(deg == 0, r, r / np.maximum(deg, 1))
     return vals.astype(np.float32)

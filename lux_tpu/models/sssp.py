@@ -43,21 +43,22 @@ class SSSP(PushProgram):
 
 
 def reference_sssp(graph: Graph, start: int = 0) -> np.ndarray:
-    """Host BFS oracle (hop counts; unreached = nv, like the reference)."""
+    """Host BFS oracle (hop counts; unreached = nv, like the reference),
+    level-synchronous over the CSR in numpy."""
     csr = graph.csr()
     dist = np.full(graph.nv, graph.nv, dtype=np.uint32)
     dist[start] = 0
-    frontier = [start]
+    frontier = np.array([start], dtype=np.int64)
     d = 0
-    while frontier:
+    while frontier.size:
         d += 1
-        nxt = []
-        for u in frontier:
-            for v in csr.col_dst[csr.row_ptr[u] : csr.row_ptr[u + 1]]:
-                if dist[v] > d:
-                    dist[v] = d
-                    nxt.append(int(v))
-        frontier = nxt
+        lo = csr.row_ptr[frontier]
+        cnt = csr.row_ptr[frontier + 1] - lo
+        # Edge slots of every frontier vertex, concatenated.
+        first = np.repeat(lo - np.cumsum(cnt) + cnt, cnt)
+        nbr = csr.col_dst[first + np.arange(first.size)]
+        frontier = np.unique(nbr[dist[nbr] > d]).astype(np.int64)
+        dist[frontier] = d
     return dist
 
 

@@ -20,13 +20,13 @@ Two executors with identical semantics:
 - :func:`level_apply_ref` — pure ``jax.numpy`` (row gather +
   ``take_along_axis`` + ``where``), used off-TPU so the whole pipeline
   is exact and testable on the CPU tier-1 mesh;
-- the Pallas path — derived from the validated probe kernel
-  (tools/probe_merge_kernel.py ``k_merge``): grid (S,), (1, 128)
-  blocks, ``pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=2)`` with
-  per-row dynamic input offsets. (An 8-row-batched variant with
-  (8, 128) blocks and block-aligned offsets is the obvious next step
-  once row batching lands in the planner output; the per-row form is
-  the one the plan contract guarantees today.)
+- the Pallas path — grid over ``ROWS``-row output blocks with
+  ``pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=2)``: the per-row
+  offsets are scalar-prefetched, each output row's A and B source rows
+  are DMA'd from the HBM-resident stream into VMEM, and the int8 code
+  plane is read in (32, 128) blocks, its native tiling. (A (1, 128)
+  block is refused by the TPU lowering: the last two block dims must be
+  multiples of (8, 128) or the whole array.)
 
 Intermediate pad lanes are never masked — the planner's code planes
 only ever address lanes that hold reals (asserted by the host
@@ -42,6 +42,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from lux_tpu.ops.merge_tail_plan import GroupedTailPlan
 from lux_tpu.ops.segment import segment_sum_by_rowptr
@@ -101,36 +103,65 @@ def level_apply_ref(x, arow, brow, codes):
     return jnp.where(codes >= 0, ga, gb)
 
 
-def _k_level(arow_ref, brow_ref, a_ref, b_ref, c_ref, o_ref):
+ROWS = 32  # output rows per grid step: the int8 code plane's tile height
+
+
+def _k_level(arow_ref, brow_ref, x_hbm, c_ref, o_ref, abuf, bbuf, sem):
+    base = pl.program_id(0) * ROWS
+
+    def row_copies(i, a_src, b_src):
+        return (
+            pltpu.make_async_copy(
+                x_hbm.at[pl.ds(a_src, 1)], abuf.at[pl.ds(i, 1)], sem.at[0]),
+            pltpu.make_async_copy(
+                x_hbm.at[pl.ds(b_src, 1)], bbuf.at[pl.ds(i, 1)], sem.at[1]),
+        )
+
+    for i in range(ROWS):
+        for cp in row_copies(i, arow_ref[base + i], brow_ref[base + i]):
+            cp.start()
+    for i in range(ROWS):
+        for cp in row_copies(i, 0, 0):
+            cp.wait()
     v = c_ref[...].astype(jnp.int32)   # int8 bitwise ops don't lower
     lane = v & 127
-    ga = jnp.take_along_axis(a_ref[...], lane, axis=1)
-    gb = jnp.take_along_axis(b_ref[...], lane, axis=1)
+    ga = jnp.take_along_axis(abuf[...], lane, axis=1)
+    gb = jnp.take_along_axis(bbuf[...], lane, axis=1)
     o_ref[...] = jnp.where(v >= 0, ga, gb)
 
 
-def level_apply_pallas(x, arow, brow, codes):
-    """One network level as a Pallas call with per-row scalar-prefetched
-    input offsets (probe-validated pattern)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
+def level_apply_pallas(x, arow, brow, codes, interpret=False):
+    """One network level as a Pallas call: scalar-prefetched per-row
+    input offsets, row DMAs from the HBM stream, lane routing in VMEM.
+    Rows past ``codes.shape[0]`` in the last block read row 0 and are
+    dropped."""
     s = codes.shape[0]
+    pad = -s % ROWS
+    if pad:
+        arow = jnp.pad(arow, (0, pad))
+        brow = jnp.pad(brow, (0, pad))
+        codes = jnp.pad(codes, ((0, pad), (0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(s,),
+        grid=((s + pad) // ROWS,),
         in_specs=[
-            pl.BlockSpec((1, BLOCK), lambda g, ar, br: (ar[g], 0)),
-            pl.BlockSpec((1, BLOCK), lambda g, ar, br: (br[g], 0)),
-            pl.BlockSpec((1, BLOCK), lambda g, ar, br: (g, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((ROWS, BLOCK), lambda g, ar, br: (g, 0)),
         ],
-        out_specs=pl.BlockSpec((1, BLOCK), lambda g, ar, br: (g, 0)),
+        out_specs=pl.BlockSpec((ROWS, BLOCK), lambda g, ar, br: (g, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((ROWS, BLOCK), jnp.float32),
+            pltpu.VMEM((ROWS, BLOCK), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _k_level,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, BLOCK), jnp.float32),
-    )(arow, brow, x, x, codes)
+        out_shape=jax.ShapeDtypeStruct((s + pad, BLOCK), jnp.float32),
+        interpret=interpret,
+    )(arow, brow, x.astype(jnp.float32), codes)
+    return out[:s]
 
 
 def level_apply(x, arow, brow, codes, use_pallas=None):

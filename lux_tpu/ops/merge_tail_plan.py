@@ -12,7 +12,7 @@ Pipeline (:func:`plan_grouped_tail`):
 1. group tail edges into runs by source block (``tail_sb``) — one
    gathered x2d row then serves up to 128 edges of the run per stream
    row (the whole point of the grouped tail);
-2. skew mitigation, measured-best in PERF.md (24-27x -> 1.85x):
+2. skew mitigation, measured-best in PERF_NOTES.md (24-27x -> 1.85x):
    INTERLEAVED splitting of big runs (piece k takes every s-th element
    so every piece spans the full dst range) + size-sorted pairing
    (leaf i of the merge tree is the i-th largest piece, so siblings at
@@ -51,7 +51,7 @@ ALIGN_ROWS = 8            # Mosaic block granularity (rows)
 # Interleaved run splitting is OFF by default: under the copy-window
 # contract a dominant side streams at full rate, so size skew is
 # nearly free and splitting only adds row-granularity overhead
-# (measured on the PERF.md heavy-tail synthetic: no-split 1.11x mean
+# (measured on the PERF_NOTES.md heavy-tail synthetic: no-split 1.11x mean
 # inflation vs 1.45x at split_rows=32; geometric sizes 1.01x vs 2.13x).
 # The knob remains for distributions where dst-interleaving stalls
 # dominate.
@@ -97,7 +97,7 @@ def split_runs_interleaved(run_of, pos_in_run, sizes, max_len: int):
     Piece k of a run split s ways takes elements k, k+s, k+2s, ... —
     every piece spans the run's full dst range, which is what makes
     size-sorted pairing effective (dst-RANGE chunks pair into
-    disjoint-range siblings that merge sequentially, PERF.md).
+    disjoint-range siblings that merge sequentially, PERF_NOTES.md).
     Returns (piece_of, pos_in_piece, piece_sizes); pieces stay
     dst-sorted because they are subsequences.
     """

@@ -1,7 +1,7 @@
 """Reference (host, unoptimized) scheduler for the merge-network tail.
 
 This is the round-4 groundwork for the source-block-grouped tail
-(PERF.md "grouped-tail / merge-network design"): a correct, executable
+(PERF_NOTES.md "grouped-tail / merge-network design"): a correct, executable
 specification of the routing construction, validated by simulation in
 tests/test_merge_tail.py. It is NOT wired into any executor and is not
 performance code — the real planner must vectorize the walk (34M reals
@@ -151,7 +151,7 @@ def schedule_grouped(runs, align_rows: int = 1):
     lane v, v < 0 side B lane v & 127). A row whose codes are
     single-sided is a COPY row — a drained or dominant side streams at
     full rate (128/row) instead of stalling at the 64/64 merge rate,
-    which is the entire point (PERF.md: 1.85x -> target <1.5x). The
+    which is the entire point (PERF_NOTES.md: 1.85x -> target <1.5x). The
     walk emits a copy row exactly when the next <=128 merged reals are
     single-sided within one input row.
 

@@ -80,7 +80,7 @@ def take1d_blocked(z: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
 
     TPU scalar gathers run at ~8.5 ns/element (the VPU has no fine-grained
     HBM access) while aligned 128-lane *row* gathers stream at full HBM
-    bandwidth (~0.9 ns/row, PERF.md). So: fetch the 128-block containing
+    bandwidth (~0.9 ns/row, PERF_NOTES.md). So: fetch the 128-block containing
     each element as a row, then select the lane with an on-the-fly one-hot
     — ~1.5 KB of streamed traffic per element instead of a ~4.4 KB-equiv
     scalarized access. Exact (pure selection). Chunked with a scan so the

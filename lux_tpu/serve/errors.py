@@ -1,4 +1,4 @@
-"""Serving-layer error taxonomy.
+"""Serving-layer error classes.
 
 Each class maps to one HTTP status in serve/http.py and one `obs`
 counter, so clients and dashboards see the same three failure modes:

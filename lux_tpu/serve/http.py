@@ -46,7 +46,7 @@ clients can observe a hot-swap from response headers alone, and is
 counted into ``lux_requests_total{code=...}``. Degraded serving (a
 failed N+1 warm; version N still answering) adds ``X-Lux-Degraded``
 with the version that failed; shed responses (429/503/504) carry
-``Retry-After`` seconds from the error taxonomy (serve/errors.py) or
+``Retry-After`` seconds from the error classes (serve/errors.py) or
 the circuit breaker's cooldown remainder (serve/breaker.py). Query
 responses answered by engines built under a tuned config
 (lux_tpu/tune) add ``X-Lux-Tuned: <tuneconf.v1 artifact id>``.
@@ -58,7 +58,7 @@ mode) dumps a flight.v1 postmortem to ``LUX_FLIGHT_DIR``; ``SIGUSR2``
 toggles a profiler capture window under ``LUX_PROF_DIR``.
 
 Error mapping: ``BadQueryError`` → 400, ``QueueFullError`` → 429,
-``DeadlineExceededError`` → 504 (serve/errors.py owns the taxonomy).
+``DeadlineExceededError`` → 504 (serve/errors.py owns the error classes).
 
 ``ThreadingHTTPServer`` gives one thread per in-flight request, which is
 exactly what the micro-batcher wants: concurrent requests are all parked
@@ -410,6 +410,9 @@ def main(argv: Optional[list] = None) -> int:
     args = p.parse_args(argv)
 
     log = get_logger("serve")
+    from lux_tpu.utils.platform import enable_compile_cache
+
+    log.info("compile cache: %s", enable_compile_cache())
     cfg = ServeConfig(
         max_batch=args.max_batch,
         window_s=args.window_ms / 1e3,

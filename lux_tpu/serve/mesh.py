@@ -103,20 +103,13 @@ def _ensure_devices(n: int, spec: str) -> None:
     os.environ["XLA_FLAGS"] = virtual_cpu_flags(n)
     import jax
 
-    forced = flags.get("LUX_PLATFORM")
-    if forced:
-        try:
-            jax.config.update("jax_platforms", forced)
-        # luxlint: disable=LUX007 -- best-effort: the jax.devices() check below surfaces any failure
-        except Exception:
-            pass   # backend already up; the device check decides below
     have = len(jax.devices())
     if have < n:
         raise ValueError(
             f"serving mesh {spec!r} needs {n} devices but only {have} "
             f"are visible. On CPU, set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={n} (and "
-            "LUX_PLATFORM=cpu) before any jax import — "
+            "JAX_PLATFORMS=cpu) before any jax import — "
             "tools/serve_bench.py --mesh does this automatically"
         )
 

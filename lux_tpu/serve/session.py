@@ -1646,6 +1646,14 @@ class Session:
 
     # -- introspection / lifecycle ---------------------------------------
 
+    def _device_block(self) -> dict:
+        """What the engines run on, as JAX reports it."""
+        import jax
+
+        devs = jax.devices()
+        return {"platform": devs[0].platform,
+                "kind": devs[0].device_kind, "count": len(devs)}
+
     def _mesh_block(self) -> dict:
         """The serving-mesh view shared by ``stats`` and ``/statusz``:
         mesh spec/shape plus live pool entries grouped by the mesh-shape
@@ -1798,6 +1806,7 @@ class Session:
             "cache": self.cache.stats(),
             "batcher": self.batcher.stats(),
             "mesh": self._mesh_block(),
+            "device": self._device_block(),
             "tune": self._tune_block(),
             "programs": self._programs_block(),
             "memory": self._memory_block(),
@@ -1839,6 +1848,7 @@ class Session:
             "cache_hit_rate": (c["hits"] / probes) if probes else None,
             "batch_size": self.batcher.batch_histogram(),
             "mesh": self._mesh_block(),
+            "device": self._device_block(),
             "tune": self._tune_block(),
             "programs": self._programs_block(),
             "memory": self._memory_block(),

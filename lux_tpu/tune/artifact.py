@@ -6,7 +6,7 @@ names the workload it was searched for (graph fingerprint, program,
 engine kind, mesh shape, device kind), the winning knob assignment, and
 the full score table with the run-ledger record ids of every probe that
 produced it — so ``luxlint --tune`` can verify the selection offline
-and PERF.md claims can cite it. Files are one JSON object each,
+and PERF_NOTES.md claims can cite it. Files are one JSON object each,
 written atomically (tmp + rename) under ``LUX_TUNE_DIR`` with a name
 derived from the key, so re-tuning the same workload replaces its
 artifact in place.

@@ -270,9 +270,7 @@ define("LUX_ICI_PEAK_GBPS", None,
        "override the roofline per-chip ICI peak (GB/s) when the "
        "device-profile registry has no row for this device_kind")
 
-# Backend / native toolchain (utils/platform.py, native/build.py)
-define("LUX_PLATFORM", None,
-       "force the JAX platform (e.g. cpu) before any backend initializes")
+# Native toolchain (native/build.py)
 define("LUX_NATIVE_CACHE", None,
        "native-library build cache dir (default ~/.cache/lux_tpu_native)",
        kind="path")

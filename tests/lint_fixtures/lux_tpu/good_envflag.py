@@ -6,5 +6,5 @@ from lux_tpu.utils import flags
 
 LEVEL = flags.get("LUX_LOG")
 SCALE = flags.get_int("LUX_SMOKE_SCALE")
-os.environ.setdefault("LUX_PLATFORM", "cpu")   # write, not a read
+os.environ.setdefault("LUX_NATIVE_CACHE", "/tmp")  # write, not a read
 os.environ["LUX_LOG"] = "DEBUG"                # store context: legal

@@ -120,7 +120,7 @@ def test_edge_chunked_auto_threshold(monkeypatch):
 
 def test_edge_chunked_src_band_parity(monkeypatch):
     # Source-band gathers (per-chunk lax.cond; the bipartite item-side
-    # src slice, PERF.md round-2 lever) must be numerically identical to
+    # src slice, PERF_NOTES.md round-2 lever) must be numerically identical to
     # full-table src gathers. Tiny chunks make user-dst chunks pure
     # item-source (narrow band) while item-dst chunks stay wide.
     from lux_tpu.engine.pull import _src_slice_plan

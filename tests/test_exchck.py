@@ -221,7 +221,7 @@ def test_artifact_unknown_format_rejected(tmp_path):
 def test_exchange_matrix_clean_and_fast():
     # The acceptance gate `make lint-exchange` runs: every full+compact
     # sharded target plus its live plan verifies clean, within the
-    # PERF.md tier budget.
+    # PERF_NOTES.md tier budget.
     report = ir.run_exchange_matrix()
     assert report.ok, report.format_human()
     assert report.summary()["schema"] == "luxlint-exchange.v1"
@@ -230,9 +230,13 @@ def test_exchange_matrix_clean_and_fast():
     assert any(n.endswith("+compact") for n in names)
     assert any(n.endswith("/plan") for n in names)
     # Round 17 grew the matrix by the gas_sharded targets plus a third
-    # (frontier) exchange mode for every frontier program; the PERF.md
-    # tier budget moved 2 s -> 4 s with it (~2.5 s measured).
-    assert report.elapsed_s <= 4.0, f"tier budget blown: {report.elapsed_s}"
+    # (frontier) exchange mode for every frontier program; the PERF_NOTES.md
+    # tier budget moved 2 s -> 4 s with it (~2.5 s measured). On jax
+    # 0.9 the parent tree crashed 40 of its 63 targets before tracing
+    # (jax.core.ClosedJaxpr moved) and still took 4.09-4.53 s here under
+    # the tier-1 xdist command; with every gas_sharded target tracing
+    # again it takes 4.64-5.71 s on the same host (PR 21), so 8 s.
+    assert report.elapsed_s <= 8.0, f"tier budget blown: {report.elapsed_s}"
 
 
 # -- the overlap proof catches the flipped body --------------------------

@@ -63,9 +63,9 @@ pid = int(sys.argv[1])
 port = sys.argv[2]
 out = sys.argv[3]
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path.insert(0, {repo!r})
 import jax
-jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 from lux_tpu.parallel.multihost import initialize, make_global_mesh
@@ -108,7 +108,6 @@ def test_two_process_pagerank_parity(tmp_path):
     worker.write_text(_WORKER.format(repo=REPO))
     out = str(tmp_path / "final.npy")
     env = dict(os.environ)
-    env.pop("LUX_PLATFORM", None)
     procs = [
         subprocess.Popen(
             [sys.executable, str(worker), str(i), port, out],

@@ -206,9 +206,6 @@ def test_exchange_bytes_sharded(tmp_path, monkeypatch):
     from lux_tpu.engine.pull_sharded import ShardedPullExecutor
     from lux_tpu.parallel.mesh import make_mesh
 
-    if not hasattr(jax, "shard_map"):
-        pytest.skip("jax.shard_map unavailable in this jax build "
-                    "(sharded engines cannot construct)")
     mpath = str(tmp_path / "m.jsonl")
     monkeypatch.setenv("LUX_METRICS", mpath)
     g = generate.rmat(8, 8, seed=1)

@@ -17,6 +17,23 @@ def test_pagerank_parity_random(strategy):
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=1e-9)
 
 
+def test_reference_pagerank_matches_scatter_add_loop():
+    """``reference_pagerank`` sums with bincount; the np.add.at form it
+    replaced sums in another order, so float64 agrees to ~1e-15."""
+    from lux_tpu.models.pagerank import ALPHA
+
+    g = generate.rmat(10, 8, seed=4)
+    deg = g.out_degrees.astype(np.float64)
+    vals = np.where(deg == 0, 1.0 / g.nv, 1.0 / g.nv / np.maximum(deg, 1))
+    for _ in range(5):
+        acc = np.zeros(g.nv)
+        np.add.at(acc, g.col_dst, vals[g.col_src])
+        r = (1.0 - ALPHA) / g.nv + ALPHA * acc
+        vals = np.where(deg == 0, r, r / np.maximum(deg, 1))
+    np.testing.assert_allclose(
+        reference_pagerank(g, 5), vals.astype(np.float32), rtol=1e-6)
+
+
 def test_pagerank_parity_rmat():
     g = generate.rmat(10, 8, seed=1)
     ex = PullExecutor(g, PageRank())

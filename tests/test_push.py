@@ -22,6 +22,31 @@ def test_sssp_path_graph():
     assert check(g, np.asarray(state.values), SSSP(), verbose=False)
 
 
+def _loop_bfs(graph, start):
+    """The per-vertex loop the vectorized ``reference_sssp`` replaced."""
+    csr = graph.csr()
+    dist = np.full(graph.nv, graph.nv, dtype=np.uint32)
+    dist[start] = 0
+    frontier, d = [start], 0
+    while frontier:
+        d += 1
+        nxt = []
+        for u in frontier:
+            for v in csr.col_dst[csr.row_ptr[u]:csr.row_ptr[u + 1]]:
+                if dist[v] > d:
+                    dist[v] = d
+                    nxt.append(int(v))
+        frontier = nxt
+    return dist
+
+
+@pytest.mark.parametrize("seed,start", [(0, 0), (1, 7), (2, 300)])
+def test_reference_sssp_matches_loop_oracle(seed, start):
+    g = generate.rmat(10, 8, seed=seed)
+    np.testing.assert_array_equal(
+        reference_sssp(g, start), _loop_bfs(g, start))
+
+
 def test_sssp_random_parity():
     g = generate.gnp(400, 2400, seed=3)
     ex = PushExecutor(g, SSSP())

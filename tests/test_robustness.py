@@ -35,7 +35,7 @@ def _graph(seed=21):
     return generate.gnp(100, 600, seed=seed)
 
 
-# -- error taxonomy --------------------------------------------------------
+# -- error classes ---------------------------------------------------------
 
 
 def test_shed_errors_carry_retry_after():

@@ -74,7 +74,7 @@ def metrics_from_headline(headline: dict) -> dict:
 
 
 def roofline_from_headline(headline: dict) -> dict:
-    """The roofline block PERF.md's evidence policy v3 requires: the
+    """The roofline block PERF_NOTES.md's evidence policy v3 requires: the
     achieved-vs-peak fractions from the headline telemetry (attached by
     obs/report.py) plus the headline's byte-model fraction."""
     out = {}

@@ -6,7 +6,7 @@ Runs the 8-way (and smaller) ShardedTiledExecutor on an R-MAT graph on
 records per-iteration wall time plus the ANALYTIC per-device collective
 volume. On this 2-core host the virtual devices share cores, so wall
 times measure correctness + dispatch overhead, NOT scaling — the
-collective-byte model is the honest scaling input (PERF.md carries the
+collective-byte model is the honest scaling input (PERF_NOTES.md carries the
 extrapolation). Usage:
 
     python tools/bench_sharded.py [scale] [iters]
@@ -15,7 +15,7 @@ import os
 import sys
 
 PARTS = (1, 2, 4, 8)
-os.environ.setdefault("LUX_PLATFORM", "cpu")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "")
     + f" --xla_force_host_platform_device_count={max(PARTS)}"
@@ -38,9 +38,9 @@ def main():
         ".bench_cache",
     )
 
-    from lux_tpu.utils.platform import ensure_backend
+    import jax
 
-    log(f"platform: {ensure_backend()}")
+    log(f"platform: {jax.devices()[0].platform}")
     import jax
 
     log(f"devices: {len(jax.devices())}")

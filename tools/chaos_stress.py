@@ -45,7 +45,7 @@ sys.path.insert(0, REPO)
 # Robustness knobs pinned before any lux_tpu import so flag reads and
 # module wiring see them: fast retry, a 3-failure breaker with a short
 # cooldown, and a WAL armed in a scratch dir.
-os.environ.setdefault("LUX_PLATFORM", "cpu")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ["LUX_RETRY_MAX"] = "1"
 os.environ["LUX_RETRY_BACKOFF_MS"] = "10"
 os.environ["LUX_BREAKER_THRESHOLD"] = "3"
@@ -99,10 +99,6 @@ def main() -> int:
     from lux_tpu.utils import flags
 
     scale = flags.get_int("LUX_SMOKE_SCALE")
-
-    import jax
-
-    jax.config.update("jax_platforms", flags.get("LUX_PLATFORM"))
 
     from lux_tpu.graph import EdgeEdits, SnapshotStore, generate
     from lux_tpu.obs import metrics

@@ -22,7 +22,7 @@ engine (ISSUE 17), on a 2x4 virtual CPU mesh with
    RecompileSentinel saw zero serve-phase recompiles (direction
    switches and frontier<->compact downgrades share one executable);
 6. report the frontier-vs-compact per-iteration exchange-byte budget
-   from the live plan (the PERF.md evidence).
+   from the live plan (the PERF_NOTES.md evidence).
 
 Emits a ``gas_sharded_smoke.v1`` JSON line on success. Scale with
 LUX_SMOKE_SCALE (default 10).
@@ -61,7 +61,7 @@ def get(base, path):
 
 
 def main() -> int:
-    os.environ.setdefault("LUX_PLATFORM", "cpu")
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     # Engines trace the exchange mode at build time: set it before the
     # session warms anything.
     os.environ["LUX_EXCHANGE"] = "frontier"
@@ -71,8 +71,6 @@ def main() -> int:
     import jax
 
     from lux_tpu.utils import flags
-
-    jax.config.update("jax_platforms", flags.get("LUX_PLATFORM"))
 
     from lux_tpu.engine.gas import AdaptiveExecutor, as_gas
     from lux_tpu.engine.gas_sharded import ShardedAdaptiveExecutor

@@ -64,12 +64,9 @@ def metric_value(base, name, **labels):
 
 
 def main() -> int:
-    os.environ.setdefault("LUX_PLATFORM", "cpu")
-    import jax
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
     from lux_tpu.utils import flags
-
-    jax.config.update("jax_platforms", flags.get("LUX_PLATFORM"))
 
     with tempfile.TemporaryDirectory() as td:
         ledger_dir = os.path.join(td, "ledger")

@@ -3,7 +3,7 @@
 
 Runs the full scheduler -> simulator -> fallback-kernel parity pipeline
 on random skewed run sets whose sizes match the RMAT22 tail-edge
-distribution recorded in PERF.md (per-source-block edge counts: mean
+distribution recorded in PERF_NOTES.md (per-source-block edge counts: mean
 1243, p50 283, p99 ~17k, max ~79k, cv ~2.6 — drawn here from a capped
 lognormal fit), then checks:
 
@@ -33,7 +33,7 @@ INFLATION_BOUND = 1.5
 
 
 def heavy_tail_sizes(rng, nsb):
-    """Per-source-block tail-edge counts matching PERF.md's RMAT22
+    """Per-source-block tail-edge counts matching PERF_NOTES.md's RMAT22
     stats (lognormal body, capped at the observed max)."""
     import numpy as np
 
@@ -42,10 +42,9 @@ def heavy_tail_sizes(rng, nsb):
 
 
 def main() -> int:
-    os.environ.setdefault("LUX_PLATFORM", "cpu")
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
 
-    jax.config.update("jax_platforms", os.environ["LUX_PLATFORM"])
     import jax.numpy as jnp
     import numpy as np
 

@@ -12,8 +12,7 @@ import sys, os, time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax, jax.numpy as jnp, numpy as np
-from lux_tpu.utils.platform import ensure_backend
-print("platform:", ensure_backend(), file=sys.stderr)
+print("platform:", jax.devices()[0].platform, file=sys.stderr)
 from lux_tpu.engine.pull import hard_sync
 
 ONLY = set(sys.argv[1:])

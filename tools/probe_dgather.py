@@ -14,8 +14,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax, jax.numpy as jnp, numpy as np
 from jax.experimental import pallas as pl
-from lux_tpu.utils.platform import ensure_backend
-print("platform:", ensure_backend(), file=sys.stderr)
+print("platform:", jax.devices()[0].platform, file=sys.stderr)
 from lux_tpu.engine.pull import hard_sync
 
 ONLY = set(sys.argv[1:])

@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """Probe: why the SSSP blocked-dense phases run above their byte model
-(PERF.md round-2 #5), and what the fixes buy.
+(PERF_NOTES.md round-2 #5), and what the fixes buy.
 
 - load: uint32 row-gather+select+relax (current) vs f32 sign-bit packing
 - comp: segmented (value,flag) associative min-scan (current) vs a
@@ -10,8 +10,7 @@ import sys, os, time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax, jax.numpy as jnp, numpy as np
-from lux_tpu.utils.platform import ensure_backend
-print("platform:", ensure_backend(), file=sys.stderr)
+print("platform:", jax.devices()[0].platform, file=sys.stderr)
 from lux_tpu.engine.pull import hard_sync
 
 ONLY = set(sys.argv[1:])

@@ -88,15 +88,10 @@ def check_device_math(rep):
 
 
 def main() -> int:
-    os.environ.setdefault("LUX_PLATFORM", "cpu")
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     from lux_tpu.utils.platform import virtual_cpu_flags
 
     os.environ["XLA_FLAGS"] = virtual_cpu_flags(PARTS)
-    import jax
-
-    from lux_tpu.utils import flags
-
-    jax.config.update("jax_platforms", flags.get("LUX_PLATFORM"))
 
     from lux_tpu.analysis.sentinel import RecompileSentinel
     from lux_tpu.engine.pull_sharded import ShardedPullExecutor, hard_sync

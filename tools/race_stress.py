@@ -41,7 +41,7 @@ sys.path.insert(0, REPO)
 os.environ["LUX_LOCKWATCH"] = "1"
 # Every swap's delta crosses the threshold -> compaction is forced.
 os.environ.setdefault("LUX_DELTA_COMPACT_RATIO", "0.000001")
-os.environ.setdefault("LUX_PLATFORM", "cpu")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import numpy as np  # noqa: E402
 
@@ -58,10 +58,6 @@ def main() -> int:
     from lux_tpu.utils import flags
 
     scale = flags.get_int("LUX_SMOKE_SCALE")
-
-    import jax
-
-    jax.config.update("jax_platforms", flags.get("LUX_PLATFORM"))
 
     from lux_tpu.graph import EdgeEdits, generate
     from lux_tpu.obs import metrics

@@ -23,7 +23,7 @@ import resource
 import sys
 import time
 
-os.environ.setdefault("LUX_PLATFORM", "cpu")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def main():
@@ -53,9 +53,9 @@ def main():
         print(f"# [{time.strftime('%H:%M:%S')} rss={rss:.1f}G] {msg}",
               file=sys.stderr, flush=True)
 
-    from lux_tpu.utils.platform import ensure_backend
+    import jax
 
-    log(f"platform: {ensure_backend()}")
+    log(f"platform: {jax.devices()[0].platform}")
 
     import numpy as np
 
@@ -175,7 +175,7 @@ def main():
         "note": ("P virtual CPU devices share 2 host cores — wall time "
                  "demonstrates end-to-end capability at 2^31 edges, not "
                  "throughput; collective-volume scaling model in "
-                 "SHARDED_r02.json / PERF.md"),
+                 "SHARDED_r02.json / PERF_NOTES.md"),
     }
     with open(args.out, "w") as f:
         json.dump(out, f, indent=1)

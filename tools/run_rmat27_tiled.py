@@ -19,7 +19,7 @@ import resource
 import sys
 import time
 
-os.environ.setdefault("LUX_PLATFORM", "cpu")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def main():
@@ -47,9 +47,9 @@ def main():
         print(f"# [{time.strftime('%H:%M:%S')} rss={rss:.1f}G] {msg}",
               file=sys.stderr, flush=True)
 
-    from lux_tpu.utils.platform import ensure_backend
+    import jax
 
-    log(f"platform: {ensure_backend()}")
+    log(f"platform: {jax.devices()[0].platform}")
 
     import numpy as np
 

@@ -8,7 +8,7 @@ remote server via --url (measures the full HTTP path). Prints
 p50/p95/p99 latency per app, throughput, and the achieved batch-size
 histogram from the `obs` registry, and (with --json / --json-out) emits
 a schema-versioned ``serve_bench.v1`` report — the evidence format
-PERF.md specifies for serving claims, checkable against a baseline via
+PERF_NOTES.md specifies for serving claims, checkable against a baseline via
 tools/slo_check.py (`make serve-slo`).
 
 With ``--swap-at T`` (in-process mode) a ~1% random edit batch is
@@ -19,7 +19,7 @@ can assert hot-swaps are latency- and error-neutral under load.
 With ``--mesh PxQ`` (in-process mode) the session serves from sharded
 engines on a P*Q-device mesh (virtual XLA host devices on CPU) and the
 report gains a ``mesh`` block {spec, num_parts, plans,
-exchange_bytes_per_iter} — the serving half of the PERF.md multi-chip
+exchange_bytes_per_iter} — the serving half of the PERF_NOTES.md multi-chip
 evidence.
 
 Examples:
@@ -194,7 +194,7 @@ def main() -> int:
             args.url.rstrip("/") + "/healthz", timeout=10).read())
         nv = health["nv"]
     else:
-        os.environ.setdefault("LUX_PLATFORM", "cpu")
+        os.environ.setdefault("JAX_PLATFORMS", "cpu")
         if args.mesh:
             # Virtual devices must exist before the backend initializes:
             # widen XLA_FLAGS now, exactly as the RMAT27 tooling does.
@@ -206,11 +206,7 @@ def main() -> int:
             n = math.prod(parse_mesh_spec(args.mesh))
             if n > 1:
                 os.environ["XLA_FLAGS"] = virtual_cpu_flags(n)
-        import jax
 
-        from lux_tpu.utils import flags
-
-        jax.config.update("jax_platforms", flags.get("LUX_PLATFORM"))
         from lux_tpu.graph import generate
         from lux_tpu.serve import ServeConfig, Session
 
@@ -393,7 +389,7 @@ def main() -> int:
         }
         if session is not None and mesh.get("num_parts", 1) > 1:
             # Per-device collective volume the warm sharded engines move
-            # each iteration — the serving half of the PERF.md exchange
+            # each iteration — the serving half of the PERF_NOTES.md exchange
             # evidence (the batch half comes from bench_sharded.v1).
             report["mesh"]["exchange_bytes_per_iter"] = (
                 session.mesh_exchange_bytes())

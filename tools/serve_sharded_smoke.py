@@ -60,15 +60,12 @@ def main() -> int:
     # The virtual devices must exist before the first jax import touches
     # the backend; serve/mesh.py would do this too, but doing it here
     # keeps the whole process consistent (both sessions share devices).
-    os.environ.setdefault("LUX_PLATFORM", "cpu")
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     from lux_tpu.utils.platform import virtual_cpu_flags
 
     os.environ["XLA_FLAGS"] = virtual_cpu_flags(PARTS)
-    import jax
 
     from lux_tpu.utils import flags
-
-    jax.config.update("jax_platforms", flags.get("LUX_PLATFORM"))
 
     from lux_tpu.graph import DeltaGraph, EdgeEdits, generate
     from lux_tpu.models.sssp import reference_sssp

@@ -73,10 +73,7 @@ def main() -> int:
 
     scale = flags.get_int("LUX_SMOKE_SCALE")
 
-    os.environ.setdefault("LUX_PLATFORM", "cpu")
-    import jax
-
-    jax.config.update("jax_platforms", flags.get("LUX_PLATFORM"))
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
     from lux_tpu import obs
     from lux_tpu.engine.push import PushExecutor

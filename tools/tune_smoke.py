@@ -74,15 +74,12 @@ def get(base, path):
 def main() -> int:
     # Virtual devices must exist before the first jax backend touch —
     # the same bootstrap serve_sharded_smoke uses.
-    os.environ.setdefault("LUX_PLATFORM", "cpu")
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     from lux_tpu.utils.platform import virtual_cpu_flags
 
     os.environ["XLA_FLAGS"] = virtual_cpu_flags(PARTS)
-    import jax
 
     from lux_tpu.utils import flags
-
-    jax.config.update("jax_platforms", flags.get("LUX_PLATFORM"))
 
     with tempfile.TemporaryDirectory() as td:
         tune_dir = os.path.join(td, "tune")

@@ -12,7 +12,10 @@ what only the runtime knows:
   layer's "zero recompiles after warmup" claim, machine-checked.
   Counters mirror onto the obs metrics registry
   (``lux_xla_compiles_total{key,phase}``) so ``LUX_METRICS`` dumps
-  carry compile counts per engine key.
+  carry compile counts per engine key. The same listener sums compile
+  seconds into ``lux_xla_compile_seconds_total{phase}`` for compiles
+  inside a sentinel region or a ``compile_phase`` (the batch executors'
+  warm-up).
 
 - :class:`HostTransferGuard` — a context manager that fails any
   ``jax.device_get`` / ``jax.block_until_ready`` issued inside a
@@ -37,15 +40,45 @@ _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _SENTINELS = set()
 _SENTINELS_LOCK = threading.Lock()
 _LISTENER_STATE = {"installed": False, "available": False}
+# Per-thread stack of compile phases (jax compiles synchronously on the
+# dispatching thread): sentinel regions and ``compile_phase`` push here.
+_PHASES = threading.local()
 
 
-def _dispatch(event: str, *a, **kw):
+def _phase_stack() -> list:
+    st = getattr(_PHASES, "stack", None)
+    if st is None:
+        st = _PHASES.stack = []
+    return st
+
+
+def _dispatch(event: str, duration: float = 0.0, *a, **kw):
     if event != _COMPILE_EVENT:
         return
+    st = _phase_stack()
+    if st:
+        metrics.counter("lux_xla_compile_seconds_total",
+                        {"phase": st[-1]}).inc(float(duration))
     with _SENTINELS_LOCK:
         active = list(_SENTINELS)
     for s in active:
         s._on_compile()
+
+
+@contextlib.contextmanager
+def compile_phase(phase: str):
+    """Count the seconds of every XLA compile on this thread inside the
+    block under ``lux_xla_compile_seconds_total{phase}`` (installs the
+    listener on first use). The counter exists from here on, so a phase
+    whose executables all came from the persistent cache reads 0."""
+    _ensure_listener()
+    metrics.counter("lux_xla_compile_seconds_total", {"phase": phase})
+    st = _phase_stack()
+    st.append(phase)
+    try:
+        yield
+    finally:
+        st.pop()
 
 
 def _ensure_listener() -> bool:
@@ -113,7 +146,8 @@ class RecompileSentinel:
         st = self._stack()
         st.append((phase, str(key)))
         try:
-            yield self
+            with compile_phase(phase):
+                yield self
         finally:
             st.pop()
 

@@ -19,15 +19,23 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from lux_tpu.analysis.sentinel import compile_phase
 from lux_tpu.engine.program import EdgeCtx, PullProgram, VertexCtx
 from lux_tpu.graph.graph import Graph
 from lux_tpu.obs import (
     NULL_RECORDER,
     consume_compile_seconds,
+    metrics,
     note_compile_seconds,
+    prof,
     recorder_for,
+    spans,
 )
-from lux_tpu.ops.segment import segment_reduce, segment_sum_by_rowptr
+from lux_tpu.ops.segment import (
+    cumsum0,
+    segment_reduce,
+    segment_sum_by_rowptr,
+)
 from lux_tpu.utils import flags
 from lux_tpu.utils.timing import Timer
 
@@ -73,9 +81,11 @@ def run_pipelined(step, vals, num_iters: int, flush_every: int = 8,
             # Bounded-depth flush: this sync IS the point of the
             # pipelined path (caps in-flight dispatch like the
             # reference's SLIDING_WINDOW).
-            jax.block_until_ready(vals)  # luxlint: disable=LUX001 -- designed flush point, one sync per flush_every iters
+            with spans.span("engine.sync"):
+                jax.block_until_ready(vals)  # luxlint: disable=LUX001 -- designed flush point, one sync per flush_every iters
             rec.flush(i + 1)
-    vals = hard_sync(vals)
+    with spans.span("engine.sync"):
+        vals = hard_sync(vals)
     rec.flush(num_iters)
     return vals
 
@@ -114,7 +124,9 @@ def run_maybe_fused(jrun, step, vals, num_iters: int, flush_every: int, *args,
             with Timer() as t:
                 vals = hard_sync(jrun(vals, jnp.int32(0), *args))
             rec.record_compile(t.elapsed)
-        vals = hard_sync(jrun(vals, jnp.int32(num_iters), *args))
+        vals = jrun(vals, jnp.int32(num_iters), *args)
+        with spans.span("engine.sync"):
+            vals = hard_sync(vals)
         rec.flush(num_iters)
         return vals
     return run_pipelined(step, vals, num_iters, flush_every, recorder=rec)
@@ -338,115 +350,119 @@ class PullExecutor:
         # cannot leak garbage into the next iteration's contractions.
         self._kreal, self._kpad = lane_pad_width(vshape)
 
-        chunk_plan = None
-        if self.edge_chunk:
-            # On the AUTO-selected path a boundary-dense graph (a run of
-            # near-empty rows packed into one edge window) must degrade,
-            # not fail: retry with growing windows (fewer chunks bounds
-            # the padded emit table), then fall back to the flat engine.
-            # Degrading is only legal while the resulting contribution
-            # window stays under an absolute allocation cap — otherwise
-            # the "fallback" would be the very HBM-scale array chunking
-            # exists to avoid, traded for a silent OOM. An explicit
-            # edge_chunk override keeps the hard error either way.
-            C = self.edge_chunk
-            w_eff = max(self._kpad or self._kreal, 1)   # chunked row width
-            w_flat = max(self._kreal, 1)                # flat keeps layout
-            while True:
-                try:
-                    chunk_plan = _chunk_boundary_plan(
-                        graph.row_ptr, graph.ne, C
-                    )
-                    self.edge_chunk = C
-                    break
-                except ValueError:
-                    if edge_chunk is not None:
-                        raise
-                    nxt = min(C * 4, max(graph.ne, 1))
-                    if C < graph.ne and nxt * w_eff * 4 <= DEGRADE_CAP_BYTES:
-                        C = nxt
-                        continue
-                    if graph.ne * w_flat * 4 <= DEGRADE_CAP_BYTES:
-                        import warnings
-
-                        warnings.warn(
-                            "edge-chunked plan does not compress on this "
-                            "graph — degrading to the flat engine "
-                            f"({graph.ne * w_flat * 4 >> 20} MB flat "
-                            "contributions)"
+        with spans.span("build.plan"):
+            chunk_plan = None
+            if self.edge_chunk:
+                # On the AUTO-selected path a boundary-dense graph (a run of
+                # near-empty rows packed into one edge window) must degrade,
+                # not fail: retry with growing windows (fewer chunks bounds
+                # the padded emit table), then fall back to the flat engine.
+                # Degrading is only legal while the resulting contribution
+                # window stays under an absolute allocation cap — otherwise
+                # the "fallback" would be the very HBM-scale array chunking
+                # exists to avoid, traded for a silent OOM. An explicit
+                # edge_chunk override keeps the hard error either way.
+                C = self.edge_chunk
+                w_eff = max(self._kpad or self._kreal, 1)   # chunked row width
+                w_flat = max(self._kreal, 1)                # flat keeps layout
+                while True:
+                    try:
+                        chunk_plan = _chunk_boundary_plan(
+                            graph.row_ptr, graph.ne, C
                         )
-                        self.edge_chunk = 0
+                        self.edge_chunk = C
                         break
-                    raise   # no safe degrade: surface the actionable error
-        if not self.edge_chunk:
-            self._kpad = 0   # the flat path keeps the external layout
+                    except ValueError:
+                        if edge_chunk is not None:
+                            raise
+                        nxt = min(C * 4, max(graph.ne, 1))
+                        if (C < graph.ne
+                                and nxt * w_eff * 4 <= DEGRADE_CAP_BYTES):
+                            C = nxt
+                            continue
+                        if graph.ne * w_flat * 4 <= DEGRADE_CAP_BYTES:
+                            import warnings
 
-        if self.edge_chunk:
-            C = self.edge_chunk
-            nchunks, bnd_pos, gidx, bchunk = chunk_plan
-            pad = nchunks * C - graph.ne
+                            warnings.warn(
+                                "edge-chunked plan does not compress on this "
+                                "graph — degrading to the flat engine "
+                                f"({graph.ne * w_flat * 4 >> 20} MB flat "
+                                "contributions)"
+                            )
+                            self.edge_chunk = 0
+                            break
+                        raise   # no safe degrade: surface the actionable error
+            if not self.edge_chunk:
+                self._kpad = 0   # the flat path keeps the external layout
 
-            # dst-slice gather (see _dst_slice_plan): auto-on when the
-            # slice traffic (nchunks x span rows/iter) is well under the
-            # edge gather traffic it replaces; LUX_DST_SLICE=0/1 overrides.
-            span, dst_lo = _dst_slice_plan(
-                graph.col_dst, graph.ne, C, graph.nv
-            )
-            knob = flags.tristate("LUX_DST_SLICE", strict=False)
-            auto = 0 < span < graph.nv and nchunks * span <= graph.ne // 2
-            self._dst_span = span if (
-                (knob is True and span < graph.nv)
-                or (knob is not False and auto)
-            ) else 0
+            self._dst_span = self._src_span = 0
+            if self.edge_chunk:
+                C = self.edge_chunk
+                nchunks, bnd_pos, gidx, bchunk = chunk_plan
+                pad = nchunks * C - graph.ne
 
-            # Source-band gathers (per-chunk lax.cond — see
-            # _src_slice_plan); LUX_SRC_SLICE=0/1 overrides the auto-on.
-            row_b = max(self._kpad or self._kreal, 1) * 4
-            span_s, src_lo, src_banded = _src_slice_plan(
-                graph.col_src, graph.ne, C, graph.nv, row_b
-            )
-            sknob = flags.tristate("LUX_SRC_SLICE", strict=False)
-            # Traffic guard (mirrors the dst path's): each banded chunk
-            # pays ~2*span rows of slice copy to save ~C rows of
-            # big-table gather at ~5x the sub-cliff rate — only a clear
-            # win while the span stays within a couple of chunk sizes.
-            s_auto = 0 < span_s <= 2 * C
-            self._src_span = span_s if (
-                (sknob is True and span_s)
-                or (sknob is not False and s_auto)
-            ) else 0
+                # dst-slice gather (see _dst_slice_plan): auto-on when the
+                # slice traffic (nchunks x span rows/iter) is well under the
+                # edge gather traffic it replaces; LUX_DST_SLICE=0/1 overrides.
+                span, dst_lo = _dst_slice_plan(
+                    graph.col_dst, graph.ne, C, graph.nv
+                )
+                knob = flags.tristate("LUX_DST_SLICE", strict=False)
+                auto = 0 < span < graph.nv and nchunks * span <= graph.ne // 2
+                self._dst_span = span if (
+                    (knob is True and span < graph.nv)
+                    or (knob is not False and auto)
+                ) else 0
 
-            def padded(a):
-                return np.pad(a, (0, pad)).reshape(nchunks, C)
+                # Source-band gathers (per-chunk lax.cond — see
+                # _src_slice_plan); LUX_SRC_SLICE=0/1 overrides the auto-on.
+                row_b = max(self._kpad or self._kreal, 1) * 4
+                span_s, src_lo, src_banded = _src_slice_plan(
+                    graph.col_src, graph.ne, C, graph.nv, row_b
+                )
+                sknob = flags.tristate("LUX_SRC_SLICE", strict=False)
+                # Traffic guard (mirrors the dst path's): each banded chunk
+                # pays ~2*span rows of slice copy to save ~C rows of
+                # big-table gather at ~5x the sub-cliff rate — only a clear
+                # win while the span stays within a couple of chunk sizes.
+                s_auto = 0 < span_s <= 2 * C
+                self._src_span = span_s if (
+                    (sknob is True and span_s)
+                    or (sknob is not False and s_auto)
+                ) else 0
 
-            self.dgraph = _ChunkedGraph(
-                col_src=put(padded(graph.col_src.astype(np.int32))),
-                seg_ids=put(padded(graph.col_dst.astype(np.int32))),
-                weights=(
-                    None if graph.weights is None
-                    else put(padded(graph.weights))
-                ),
-                bnd_pos=put(bnd_pos),
-                gather_idx=put(gidx),
-                bnd_chunk=put(bchunk),
-                dst_lo=put(dst_lo),
-                src_lo=put(src_lo),
-                src_banded=put(src_banded),
-                out_degrees=put(graph.out_degrees.astype(np.int32)),
-                in_degrees=put(graph.in_degrees.astype(np.int32)),
-            )
-        else:
-            self._dst_span = 0
-            self._src_span = 0
-            eidx = _edge_index_dtype(graph.ne)
-            self.dgraph = _DeviceGraph(
-                col_src=put(graph.col_src.astype(np.int32)),
-                seg_ids=put(graph.col_dst),
-                row_ptr=put(graph.row_ptr.astype(eidx)),
-                weights=None if graph.weights is None else put(graph.weights),
-                out_degrees=put(graph.out_degrees.astype(np.int32)),
-                in_degrees=put(graph.in_degrees.astype(np.int32)),
-            )
+        with spans.span("build.upload"):
+            if self.edge_chunk:
+                def padded(a):
+                    return np.pad(a, (0, pad)).reshape(nchunks, C)
+
+                self.dgraph = _ChunkedGraph(
+                    col_src=put(padded(graph.col_src.astype(np.int32))),
+                    seg_ids=put(padded(graph.col_dst.astype(np.int32))),
+                    weights=(
+                        None if graph.weights is None
+                        else put(padded(graph.weights))
+                    ),
+                    bnd_pos=put(bnd_pos),
+                    gather_idx=put(gidx),
+                    bnd_chunk=put(bchunk),
+                    dst_lo=put(dst_lo),
+                    src_lo=put(src_lo),
+                    src_banded=put(src_banded),
+                    out_degrees=put(graph.out_degrees.astype(np.int32)),
+                    in_degrees=put(graph.in_degrees.astype(np.int32)),
+                )
+            else:
+                eidx = _edge_index_dtype(graph.ne)
+                self.dgraph = _DeviceGraph(
+                    col_src=put(graph.col_src.astype(np.int32)),
+                    seg_ids=put(graph.col_dst),
+                    row_ptr=put(graph.row_ptr.astype(eidx)),
+                    weights=(None if graph.weights is None
+                             else put(graph.weights)),
+                    out_degrees=put(graph.out_degrees.astype(np.int32)),
+                    in_degrees=put(graph.in_degrees.astype(np.int32)),
+                )
         self._step = jax.jit(self._step_impl, donate_argnums=0)
         self._jrun = make_fused_runner(self._step_impl)
 
@@ -456,25 +472,28 @@ class PullExecutor:
         if self.edge_chunk:
             return self._chunked_step_impl(vals, dg)
         prog = self.program
-        edge = EdgeCtx(
-            src_vals=vals[dg.col_src],
-            dst_vals=vals[dg.seg_ids],
-            weights=dg.weights,
-        )
-        contrib = prog.edge_contrib(edge)
-        if prog.combiner == "sum" and self.sum_strategy == "rowptr":
-            acc = segment_sum_by_rowptr(contrib, dg.row_ptr)
-        else:
-            acc = segment_reduce(
-                contrib, dg.seg_ids, num_segments=self.graph.nv,
-                kind=prog.combiner,
+        with prof.region("lux.pull.gather"):
+            edge = EdgeCtx(
+                src_vals=vals[dg.col_src],
+                dst_vals=vals[dg.seg_ids],
+                weights=dg.weights,
             )
+        with prof.region("lux.pull.reduce"):
+            contrib = prog.edge_contrib(edge)
+            if prog.combiner == "sum" and self.sum_strategy == "rowptr":
+                acc = segment_sum_by_rowptr(contrib, dg.row_ptr)
+            else:
+                acc = segment_reduce(
+                    contrib, dg.seg_ids, num_segments=self.graph.nv,
+                    kind=prog.combiner,
+                )
         ctx = VertexCtx(
             nv=self.graph.nv,
             out_degrees=dg.out_degrees,
             in_degrees=dg.in_degrees,
         )
-        return prog.apply(vals, acc, ctx)
+        with prof.region("lux.pull.apply"):
+            return prog.apply(vals, acc, ctx)
 
     def _chunked_step_impl(
         self, vals: jnp.ndarray, dg: _ChunkedGraph
@@ -501,40 +520,44 @@ class PullExecutor:
 
         def body(_, ch):
             cs, cd, w, bnd, dlo, slo, sbanded = ch
-            if self._dst_span:
-                # dst ids are sorted, so this chunk's dst rows live in a
-                # narrow band: gather from a small dynamic slice instead
-                # of the full value table (the big-table gather cliff —
-                # PERF_NOTES.md "CF / edge-chunked engine"). dlo is pre-clamped
-                # on the host so cd - dlo ∈ [0, span) for real edges.
-                band = jax.lax.dynamic_slice_in_dim(
-                    vals, dlo, self._dst_span, axis=0
+            with prof.region("lux.pull.gather"):
+                if self._dst_span:
+                    # dst ids are sorted, so this chunk's dst rows live
+                    # in a narrow band: gather from a small dynamic slice
+                    # instead of the full value table (the big-table
+                    # gather cliff — PERF_NOTES.md "CF / edge-chunked
+                    # engine"). dlo is pre-clamped on the host so
+                    # cd - dlo ∈ [0, span) for real edges.
+                    band = jax.lax.dynamic_slice_in_dim(
+                        vals, dlo, self._dst_span, axis=0
+                    )
+                    dst_vals = band[cd - dlo]
+                else:
+                    dst_vals = vals[cd]
+                if self._src_span:
+                    # Narrow-source chunks (e.g. the item-sourced
+                    # user-dst half of a bipartite ratings graph) serve
+                    # src_vals from a per-chunk band too; wide chunks
+                    # keep the full-table gather (per-chunk cond — see
+                    # _src_slice_plan).
+                    src_vals = jax.lax.cond(
+                        sbanded,
+                        lambda: jax.lax.dynamic_slice_in_dim(
+                            vals, slo, self._src_span, axis=0
+                        )[jnp.clip(cs - slo, 0, self._src_span - 1)],
+                        lambda: vals[cs],
+                    )
+                else:
+                    src_vals = vals[cs]
+            with prof.region("lux.pull.reduce"):
+                edge = EdgeCtx(
+                    src_vals=src_vals, dst_vals=dst_vals, weights=w,
                 )
-                dst_vals = band[cd - dlo]
-            else:
-                dst_vals = vals[cd]
-            if self._src_span:
-                # Narrow-source chunks (e.g. the item-sourced user-dst
-                # half of a bipartite ratings graph) serve src_vals from
-                # a per-chunk band too; wide chunks keep the full-table
-                # gather (per-chunk cond — see _src_slice_plan).
-                src_vals = jax.lax.cond(
-                    sbanded,
-                    lambda: jax.lax.dynamic_slice_in_dim(
-                        vals, slo, self._src_span, axis=0
-                    )[jnp.clip(cs - slo, 0, self._src_span - 1)],
-                    lambda: vals[cs],
-                )
-            else:
-                src_vals = vals[cs]
-            edge = EdgeCtx(
-                src_vals=src_vals, dst_vals=dst_vals, weights=w,
-            )
-            contrib = prog.edge_contrib(edge)
-            c2 = contrib.reshape(contrib.shape[0], k)
-            z = jnp.cumsum(c2, axis=0)
-            zf = jnp.concatenate([jnp.zeros((1, k), z.dtype), z])
-            return 0, (zf[bnd], z[-1])
+                contrib = prog.edge_contrib(edge)
+                c2 = contrib.reshape(contrib.shape[0], k)
+                z = cumsum0(c2)
+                zf = jnp.concatenate([jnp.zeros((1, k), z.dtype), z])
+                return 0, (zf[bnd], z[-1])
 
         w = dg.weights
         xs_tail = (dg.bnd_pos, dg.dst_lo, dg.src_lo, dg.src_banded)
@@ -549,34 +572,39 @@ class PullExecutor:
             _, (zb, totals) = jax.lax.scan(
                 body, 0, (dg.col_src, dg.seg_ids, w) + xs_tail
             )
-        zg = zb.reshape(-1, k)[dg.gather_idx]           # (nv+1, k)
-        ph, pl = _dd_prefix(totals)                     # (nchunks+1, k)
-        ci = dg.bnd_chunk
-        acc = (
-            (zg[1:] - zg[:-1])
-            + (ph[ci[1:]] - ph[ci[:-1]])
-            + (pl[ci[1:]] - pl[ci[:-1]])
-        )
+        with prof.region("lux.pull.reduce"):
+            zg = zb.reshape(-1, k)[dg.gather_idx]       # (nv+1, k)
+            ph, pl = _dd_prefix(totals)                 # (nchunks+1, k)
+            ci = dg.bnd_chunk
+            acc = (
+                (zg[1:] - zg[:-1])
+                + (ph[ci[1:]] - ph[ci[:-1]])
+                + (pl[ci[1:]] - pl[ci[:-1]])
+            )
         ctx = VertexCtx(
             nv=self.graph.nv,
             out_degrees=dg.out_degrees,
             in_degrees=dg.in_degrees,
         )
-        if not self._kpad:
-            acc = acc.reshape((self.graph.nv,) + vshape)
-            return prog.apply(vals, acc, ctx)
-        new = prog.apply(vals, acc, ctx)
-        # Re-zero pad lanes: apply may write constants into them, which
-        # would otherwise pollute the next iteration's contractions.
-        lane = jnp.arange(k, dtype=jnp.int32)
-        return jnp.where(lane[None, :] < kreal, new, 0)
+        with prof.region("lux.pull.apply"):
+            if not self._kpad:
+                acc = acc.reshape((self.graph.nv,) + vshape)
+                return prog.apply(vals, acc, ctx)
+            new = prog.apply(vals, acc, ctx)
+            # Re-zero pad lanes: apply may write constants into them,
+            # which would otherwise pollute the next iteration's
+            # contractions.
+            lane = jnp.arange(k, dtype=jnp.int32)
+            return jnp.where(lane[None, :] < kreal, new, 0)
 
     # -- driver ----------------------------------------------------------
 
     def init_values(self) -> jnp.ndarray:
-        return jax.device_put(
-            jnp.asarray(self.program.init_values(self.graph)), self.device
-        )
+        with spans.span("engine.init"):
+            return jax.device_put(
+                jnp.asarray(self.program.init_values(self.graph)),
+                self.device,
+            )
 
     def _lane_pad(self, vals: jnp.ndarray) -> jnp.ndarray:
         return jnp.pad(vals, ((0, 0), (0, self._kpad - self._kreal)))
@@ -595,7 +623,8 @@ class PullExecutor:
         """Run one throwaway step through the run() path outside any timed
         region (the reference's kernels are compiled at build time, so its
         ELAPSED TIME never includes compilation)."""
-        with Timer() as t:
+        with spans.span("engine.warmup"), compile_phase("warmup"), \
+                Timer() as t:
             hard_sync(self.step(self.init_values()))
         note_compile_seconds(self, t.elapsed)
 
@@ -622,32 +651,41 @@ class PullExecutor:
         flush_every: int = 8,
         recorder=None,
     ):
-        if vals is None:
-            vals = self.init_values()
-        rec = recorder if recorder is not None else recorder_for(
-            "pull", self.graph, self.program)
-        rec.start()
-        if rec.enabled:
-            rec.record_compile(consume_compile_seconds(self))
-            from lux_tpu.obs import engobs
-            rec.set_hbm_bytes(engobs.hbm_bytes_per_iter(
-                self.graph.nv, self.graph.ne, k=max(self._kreal, 1)))
-        if self._kpad:
-            padded = run_maybe_fused(
-                self._jrun,
-                lambda v: self._step(v, self.dgraph),
-                self._lane_pad(jnp.asarray(vals)),
-                num_iters, flush_every, self.dgraph,
-                recorder=rec,
-            )
-            out = hard_sync(padded[:, : self._kreal])
-        else:
-            out = run_maybe_fused(
-                self._jrun, self.step, vals, num_iters, flush_every,
-                self.dgraph, recorder=rec,
-            )
-        rec.finish()
-        return out
+        with spans.span("engine.run"):
+            if vals is None:
+                vals = self.init_values()
+            rec = recorder if recorder is not None else recorder_for(
+                "pull", self.graph, self.program)
+            rec.start()
+            if rec.enabled:
+                rec.record_compile(consume_compile_seconds(self))
+                from lux_tpu.obs import engobs
+                rec.set_hbm_bytes(engobs.hbm_bytes_per_iter(
+                    self.graph.nv, self.graph.ne, k=max(self._kreal, 1)))
+            if self._kpad:
+                padded = run_maybe_fused(
+                    self._jrun,
+                    lambda v: self._step(v, self.dgraph),
+                    self._lane_pad(jnp.asarray(vals)),
+                    num_iters, flush_every, self.dgraph,
+                    recorder=rec,
+                )
+                out = hard_sync(padded[:, : self._kreal])
+            else:
+                out = run_maybe_fused(
+                    self._jrun, self.step, vals, num_iters, flush_every,
+                    self.dgraph, recorder=rec,
+                )
+            rec.finish()
+            count_iterations("pull", num_iters)
+            return out
+
+
+def count_iterations(engine: str, n: int) -> None:
+    """``lux_engine_iterations_total{engine,branch="all"}``, counted at
+    the end of a run (engines with one branch)."""
+    metrics.counter("lux_engine_iterations_total",
+                    {"engine": engine, "branch": "all"}).inc(n)
 
 
 jax.tree_util.register_dataclass(

@@ -45,15 +45,19 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from lux_tpu.analysis.sentinel import compile_phase
 from lux_tpu.engine.pull import hard_sync
 from lux_tpu.graph.graph import Graph
 from lux_tpu.obs import (
     NULL_RECORDER,
     consume_compile_seconds,
+    engine_label,
     engobs,
+    metrics,
     note_compile_seconds,
     prof,
     recorder_for,
+    spans,
 )
 from lux_tpu.ops.segment import identity_for, segment_reduce
 from lux_tpu.parallel.mesh import PARTS_AXIS, make_mesh, parts_sharding
@@ -408,12 +412,13 @@ class PushExecutor:
         )
 
     def _merge_update(self, state: PushState, acc):
-        if self.program.combiner == "min":
-            new = jnp.minimum(state.values, acc)
-        else:
-            new = jnp.maximum(state.values, acc)
-        frontier = new != state.values
-        return PushState(new, frontier), frontier.sum(dtype=jnp.int32)
+        with prof.region("lux.push.update"):
+            if self.program.combiner == "min":
+                new = jnp.minimum(state.values, acc)
+            else:
+                new = jnp.maximum(state.values, acc)
+            frontier = new != state.values
+            return PushState(new, frontier), frontier.sum(dtype=jnp.int32)
 
     def _bd_load(self, state: PushState, dg):
         """Per-edge candidates via the packed-table row-gather + lane
@@ -448,11 +453,12 @@ class PushExecutor:
         )
 
     def _dense_iter(self, state: PushState, dg):
-        if self.blocked_dense:
-            acc = self._bd_comp(self._bd_load(state, dg), dg)
-            return self._merge_update(state, acc)
-        src_vals, src_front = self._d_load(state, dg)
-        return self._merge_update(state, self._d_comp(src_vals, src_front, dg))
+        with prof.region("lux.push.dense"):
+            if self.blocked_dense:
+                acc = self._bd_comp(self._bd_load(state, dg), dg)
+            else:
+                acc = self._d_comp(*self._d_load(state, dg), dg)
+        return self._merge_update(state, acc)
 
     # -- sparse (push-direction) stages -----------------------------------
 
@@ -486,16 +492,18 @@ class PushExecutor:
     def _s_update(self, state: PushState, cand, dst):
         """Deterministic scatter-combine into the values (unlike the
         reference's atomicMin, sssp_gpu.cu:48-61)."""
-        if self.program.combiner == "min":
-            new = state.values.at[dst].min(cand)
-        else:
-            new = state.values.at[dst].max(cand)
-        frontier = new != state.values
-        return PushState(new, frontier), frontier.sum(dtype=jnp.int32)
+        with prof.region("lux.push.update"):
+            if self.program.combiner == "min":
+                new = state.values.at[dst].min(cand)
+            else:
+                new = state.values.at[dst].max(cand)
+            frontier = new != state.values
+            return PushState(new, frontier), frontier.sum(dtype=jnp.int32)
 
     def _sparse_iter(self, state: PushState, dg, Q=None, E=None):
-        q, start, deg = self._s_load(state, dg, Q)
-        cand, dst = self._s_comp(state, q, start, deg, dg, E)
+        with prof.region("lux.push.sparse"):
+            q, start, deg = self._s_load(state, dg, Q)
+            cand, dst = self._s_comp(state, q, start, deg, dg, E)
         return self._s_update(state, cand, dst)
 
     # -- adaptive combination --------------------------------------------
@@ -506,11 +514,12 @@ class PushExecutor:
         (sssp_gpu.cu:424-458); uint32 out-edge sums are exact for any
         total <= 2^32 > ne, so a tier can never be selected past its
         edge budget by rounding error."""
-        cnt = state.frontier.sum(dtype=jnp.int32)
-        out_edges = jnp.where(
-            state.frontier, dg["out_degrees"].astype(jnp.uint32), 0
-        ).sum(dtype=jnp.uint32)
-        return _tier_index(cnt, out_edges, self.tiers)
+        with prof.region("lux.push.decide"):
+            cnt = state.frontier.sum(dtype=jnp.int32)
+            out_edges = jnp.where(
+                state.frontier, dg["out_degrees"].astype(jnp.uint32), 0
+            ).sum(dtype=jnp.uint32)
+            return _tier_index(cnt, out_edges, self.tiers)
 
     def _one_iter(self, state: PushState, dg):
         if not self.sparse:
@@ -627,15 +636,16 @@ class PushExecutor:
         return new_state, int(jax.device_get(cnt)), times
 
     def init_state(self, **kw) -> PushState:
-        vals = jax.device_put(
-            jnp.asarray(self.program.init_values(self.graph, **kw)),
-            self.device,
-        )
-        fr = jax.device_put(
-            jnp.asarray(self.program.init_frontier(self.graph, **kw)),
-            self.device,
-        )
-        return PushState(vals, fr)
+        with spans.span("engine.init"):
+            vals = jax.device_put(
+                jnp.asarray(self.program.init_values(self.graph, **kw)),
+                self.device,
+            )
+            fr = jax.device_put(
+                jnp.asarray(self.program.init_frontier(self.graph, **kw)),
+                self.device,
+            )
+            return PushState(vals, fr)
 
     def step(self, state: PushState):
         return self._step(state, self._dg)
@@ -654,20 +664,21 @@ class PushExecutor:
         exit; the host reads back one count batch per chunk. The number of
         iterations served by the sparse (push-direction) branch is left in
         ``self.sparse_iters`` after each run."""
-        if state is None:
-            state = self.init_state(**init_kw)
-        rec = recorder if recorder is not None else recorder_for(
-            "push", self.graph, self.program)
-        rec.start()
-        if rec.enabled:
-            rec.record_compile(consume_compile_seconds(self))
-            rec.set_hbm_bytes(engobs.hbm_bytes_per_iter(
-                self.graph.nv, self.graph.ne))
-        state, total, self.sparse_iters = _run_to_fixpoint(
-            self._multi, state, max_iters, chunk, recorder=rec
-        )
-        rec.finish()
-        return state, total
+        with spans.span("engine.run"):
+            if state is None:
+                state = self.init_state(**init_kw)
+            rec = recorder if recorder is not None else recorder_for(
+                "push", self.graph, self.program)
+            rec.start()
+            if rec.enabled:
+                rec.record_compile(consume_compile_seconds(self))
+                rec.set_hbm_bytes(engobs.hbm_bytes_per_iter(
+                    self.graph.nv, self.graph.ne))
+            state, total, self.sparse_iters = _run_to_fixpoint(
+                self._multi, state, max_iters, chunk, recorder=rec
+            )
+            rec.finish()
+            return state, total
 
     def _multi(self, state: PushState, limit: int, k: int):
         return self._multi_jit(state, self._dg, k, limit=jnp.int32(limit))
@@ -675,7 +686,8 @@ class PushExecutor:
     def warmup(self, chunk: int = 16, **init_kw):
         """Run one throwaway iteration through the exact run() path so
         ELAPSED TIME excludes XLA compilation AND first-transfer setup."""
-        with Timer() as t:
+        with spans.span("engine.warmup"), compile_phase("warmup"), \
+                Timer() as t:
             _run_to_fixpoint(self._multi, self.init_state(**init_kw), 1, chunk)
         note_compile_seconds(self, t.elapsed)
 
@@ -694,6 +706,14 @@ class PushExecutor:
 
 def _run_to_fixpoint(multi, state, max_iters, chunk, recorder=None):
     rec = recorder if recorder is not None else NULL_RECORDER
+    # ``multi`` is an executor's bound ``_multi``: its counters' label.
+    engine = engine_label(multi.__self__)
+    n_chunks = metrics.counter("lux_engine_chunks_total", {"engine": engine})
+    n_iters = {
+        b: metrics.counter("lux_engine_iterations_total",
+                           {"engine": engine, "branch": b})
+        for b in ("dense", "sparse")
+    }
     total = 0
     sparse_total = 0
     while True:
@@ -701,25 +721,32 @@ def _run_to_fixpoint(multi, state, max_iters, chunk, recorder=None):
         if limit <= 0:
             break
         k = chunk
-        state, counts, flags, done, last = multi(state, limit, k)
+        with spans.span("push.chunk"):
+            state, counts, flags, done, last = multi(state, limit, k)
         # One batched transfer: every device_get is a host round-trip,
         # so fetch everything together.
-        # luxlint: disable=LUX001 -- one batched fetch per chunk (not per iter) is the fixpoint design
-        counts_h, flags_h, done_h, last_h = jax.device_get(
-            (counts, flags, done, last)
-        )
+        with spans.span("push.readback"):
+            # luxlint: disable=LUX001 -- one batched fetch per chunk (not per iter) is the fixpoint design
+            counts_h, flags_h, done_h, last_h = jax.device_get(
+                (counts, flags, done, last)
+            )
         done_i = int(np.asarray(done_h).reshape(-1)[0])
         last_i = int(np.asarray(last_h).reshape(-1)[0])
         fl = np.asarray(flags_h).reshape(-1, k)[0][:done_i]
-        sparse_total += int(fl.sum())
+        n_sparse = int(fl.sum())
+        sparse_total += n_sparse
         total += done_i
+        n_chunks.inc()
+        n_iters["sparse"].inc(n_sparse)
+        n_iters["dense"].inc(done_i - n_sparse)
         # counts is (k,) single-device or psum-replicated (P, k) sharded;
         # row 0 is the global post-step active count either way.
         cnts = np.asarray(counts_h).reshape(-1, k)[0][:done_i]
         rec.flush(total, frontier_sizes=cnts, sparse_flags=fl)
         if last_i == 0 or done_i == 0:
             break
-    hard_sync(state.values)
+    with spans.span("engine.sync"):
+        hard_sync(state.values)
     rec.flush(total)
     return state, total, sparse_total
 

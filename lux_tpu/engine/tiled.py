@@ -21,8 +21,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from lux_tpu.analysis.sentinel import compile_phase
 from lux_tpu.engine.program import PullProgram, VertexCtx
 from lux_tpu.engine.pull import (
+    count_iterations,
     hard_sync,
     make_fused_runner,
     run_maybe_fused,
@@ -32,7 +34,9 @@ from lux_tpu.obs import (
     NULL_RECORDER,
     consume_compile_seconds,
     note_compile_seconds,
+    prof,
     recorder_for,
+    spans,
 )
 from lux_tpu.utils.timing import Timer
 from lux_tpu.ops.merge_tail_kernel import (
@@ -155,6 +159,12 @@ def get_cached_plan(
     return plan
 
 
+def _permute(v, idx):
+    """External <-> internal (degree-sorted) vertex order."""
+    with prof.region("lux.tiled.permute"):
+        return v[idx]
+
+
 def require_spmv_program(program: PullProgram, cls: str, fallback: str):
     """Tiled executors only run sum-combiner programs whose edge
     contribution is the source value (SpMV shape)."""
@@ -188,35 +198,38 @@ class TiledPullExecutor:
         self.graph = graph
         self.program = program
         self.device = device
-        self.plan = plan if plan is not None else plan_hybrid(
-            graph, levels=levels, budget_bytes=budget_bytes
-        )
-        p = self.plan
+        if plan is None:
+            with spans.span("build.plan"):
+                plan = plan_hybrid(
+                    graph, levels=levels, budget_bytes=budget_bytes
+                )
+        self.plan = p = plan
         put = lambda x: jax.device_put(jnp.asarray(x), device)
-        self.dhybrid = DeviceHybrid.build(
-            p, chunk_strips=chunk_strips, chunk_tail=chunk_tail,
-            device=device, pack=pack,
-        )
-        self.gtail = None
-        self.gtail_stats = None
-        if grouped_tail_enabled():
-            from lux_tpu.obs.metrics import counter, gauge
-            from lux_tpu.ops.merge_tail_plan import plan_grouped_tail
+        with spans.span("build.upload"):
+            self.dhybrid = DeviceHybrid.build(
+                p, chunk_strips=chunk_strips, chunk_tail=chunk_tail,
+                device=device, pack=pack,
+            )
+            self.gtail = None
+            self.gtail_stats = None
+            if grouped_tail_enabled():
+                from lux_tpu.obs.metrics import counter, gauge
+                from lux_tpu.ops.merge_tail_plan import plan_grouped_tail
 
-            gplan = plan_grouped_tail(
-                p.tail_sb, p.tail_lane, p.tail_row_ptr)
-            self.gtail = DeviceGroupedTail.build(gplan, device=device)
-            self.gtail_stats = gplan.stats
-            gauge("lux_grouped_tail_inflation").set(
-                gplan.stats["mean_inflation"])
-            counter("lux_grouped_tail_copy_rows").inc(
-                gplan.stats["copy_rows"])
-            counter("lux_grouped_tail_merge_rows").inc(
-                gplan.stats["merge_rows"])
-        self.out_degrees = put(p.out_degrees.astype(np.int32))
-        self.in_degrees = put(p.in_degrees.astype(np.int32))
-        self.order = put(p.order)   # external id at internal position
-        self.rank = put(p.rank)     # internal position of external id
+                gplan = plan_grouped_tail(
+                    p.tail_sb, p.tail_lane, p.tail_row_ptr)
+                self.gtail = DeviceGroupedTail.build(gplan, device=device)
+                self.gtail_stats = gplan.stats
+                gauge("lux_grouped_tail_inflation").set(
+                    gplan.stats["mean_inflation"])
+                counter("lux_grouped_tail_copy_rows").inc(
+                    gplan.stats["copy_rows"])
+                counter("lux_grouped_tail_merge_rows").inc(
+                    gplan.stats["merge_rows"])
+            self.out_degrees = put(p.out_degrees.astype(np.int32))
+            self.in_degrees = put(p.in_degrees.astype(np.int32))
+            self.order = put(p.order)   # external id at internal position
+            self.rank = put(p.rank)     # internal position of external id
         # Device data goes through jit ARGUMENTS, never closures: a
         # closed-over array is a baked-in constant, re-uploaded with every
         # compile request (multi-GB of strips would break remote compile).
@@ -229,8 +242,8 @@ class TiledPullExecutor:
         self._jstep = jax.jit(self._step_impl, donate_argnums=0)
         self._step = lambda vals: self._jstep(vals, *self._step_args)
         self._jrun = make_fused_runner(self._step_impl)
-        self._to_internal = jax.jit(lambda v, order: v[order])
-        self._to_external = jax.jit(lambda v, rank: v[rank])
+        self._to_internal = jax.jit(_permute)
+        self._to_external = jax.jit(_permute)
 
     # -- the jitted iteration (internal vertex order) --------------------
 
@@ -246,7 +259,8 @@ class TiledPullExecutor:
         self, vals, dhybrid, out_degrees, in_degrees, gtail=None
     ) -> jnp.ndarray:
         acc = hybrid_spmv(vals, dhybrid, gtail)
-        return self._apply_acc(vals, acc, out_degrees, in_degrees)
+        with prof.region("lux.tiled.apply"):
+            return self._apply_acc(vals, acc, out_degrees, in_degrees)
 
     # -- driver ----------------------------------------------------------
     # Every public entry point speaks EXTERNAL vertex ids, exactly like
@@ -254,13 +268,17 @@ class TiledPullExecutor:
     # only the private _step/_init_internal work in degree-sorted order.
 
     def _init_internal(self) -> jnp.ndarray:
-        ext = np.asarray(self.program.init_values(self.graph))
-        return jax.device_put(jnp.asarray(ext[self.plan.order]), self.device)
+        with spans.span("engine.init"):
+            ext = np.asarray(self.program.init_values(self.graph))
+            return jax.device_put(
+                jnp.asarray(ext[self.plan.order]), self.device)
 
     def init_values(self) -> jnp.ndarray:
-        return jax.device_put(
-            jnp.asarray(self.program.init_values(self.graph)), self.device
-        )
+        with spans.span("engine.init"):
+            return jax.device_put(
+                jnp.asarray(self.program.init_values(self.graph)),
+                self.device,
+            )
 
     def step(self, vals: jnp.ndarray) -> jnp.ndarray:
         """One iteration, external order in and out (boundary converts cost
@@ -361,7 +379,8 @@ class TiledPullExecutor:
     def warmup(self):
         """Compile the step and both permutation converters (run(1) with
         explicit vals exercises every jitted path run() can take)."""
-        with Timer() as t:
+        with spans.span("engine.warmup"), compile_phase("warmup"), \
+                Timer() as t:
             # NULL_RECORDER: the throwaway iteration must not write a
             # telemetry report of its own.
             hard_sync(self.run(1, vals=self.init_values(),
@@ -388,22 +407,24 @@ class TiledPullExecutor:
         flush_every: int = 8,
         recorder=None,
     ):
-        if vals is None:
-            internal = self._init_internal()
-        else:
-            internal = self._to_internal(jnp.asarray(vals), self.order)
-        rec = recorder if recorder is not None else recorder_for(
-            "tiled", self.graph, self.program)
-        rec.start()
-        if rec.enabled:
-            rec.record_compile(consume_compile_seconds(self))
-            from lux_tpu.obs import engobs
-            rec.set_hbm_bytes(engobs.hbm_bytes_per_iter(
-                self.graph.nv, self.graph.ne))
-        internal = run_maybe_fused(
-            self._jrun, self._step, internal, num_iters, flush_every,
-            *self._step_args, recorder=rec,
-        )
-        out = hard_sync(self._to_external(internal, self.rank))
-        rec.finish()
-        return out
+        with spans.span("engine.run"):
+            if vals is None:
+                internal = self._init_internal()
+            else:
+                internal = self._to_internal(jnp.asarray(vals), self.order)
+            rec = recorder if recorder is not None else recorder_for(
+                "tiled", self.graph, self.program)
+            rec.start()
+            if rec.enabled:
+                rec.record_compile(consume_compile_seconds(self))
+                from lux_tpu.obs import engobs
+                rec.set_hbm_bytes(engobs.hbm_bytes_per_iter(
+                    self.graph.nv, self.graph.ne))
+            internal = run_maybe_fused(
+                self._jrun, self._step, internal, num_iters, flush_every,
+                *self._step_args, recorder=rec,
+            )
+            out = hard_sync(self._to_external(internal, self.rank))
+            rec.finish()
+            count_iterations("tiled", num_iters)
+            return out

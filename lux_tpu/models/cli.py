@@ -160,7 +160,7 @@ def _tiled_plan(g, program, args, log):
         + "_".join(f"{r}x{t}" for r, t in levels)
         + f"_{args.tile_mb}.luxplan"
     )
-    with Timer() as t:
+    with obs.spans.span("build.plan"), Timer() as t:
         plan = get_cached_plan(
             g, path, levels=levels, budget_bytes=budget, log=log.info
         )

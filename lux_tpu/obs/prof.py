@@ -23,7 +23,10 @@ device timeline:
   only) into a ``profile.v1`` report: per-device interval-union wall
   time for exchange- vs compute-tagged ops, their intersection →
   ``realized_hidden_frac`` (directly comparable to the engobs budget),
-  device idle fraction, a top-K op table, and a steps-per-second
+  device idle fraction against the capture window (the ``lux.prof.window``
+  host span ``profile_window`` opens, else the trace's whole extent, so
+  idle time before the first op and after the last counts), a top-K op
+  table, and a steps-per-second
   cross-check against an iterlog summary.
 
 Joining device events to regions: ``jax.named_scope`` does not name
@@ -50,6 +53,7 @@ import json
 import os
 import re
 import signal
+import sys
 import threading
 
 from ..utils import flags
@@ -75,6 +79,11 @@ class ProfileParseError(RuntimeError):
 class CaptureBusyError(RuntimeError):
     """A profile capture window is already in flight in this process
     (jax.profiler supports one live session)."""
+
+
+# The host span ``profile_window`` opens around the captured work: the
+# idle-share denominator.
+WINDOW = "lux.prof.window"
 
 
 # -- region tagging --------------------------------------------------------
@@ -118,6 +127,17 @@ def region(name: str) -> _Region:
     return _Region(name)
 
 
+def annotation(name: str):
+    """A host span in the profiler's trace (``TraceAnnotation``), free
+    when no capture is live. Without jax loaded no capture can be live,
+    so this never imports it."""
+    if "jax" not in sys.modules:
+        return contextlib.nullcontext()
+    import jax.profiler
+
+    return jax.profiler.TraceAnnotation(name)
+
+
 # -- capture windows -------------------------------------------------------
 
 _CAP_IDS = itertools.count(1)
@@ -157,7 +177,8 @@ def profile_window(run, dirname=None, steps=None, op_maps=None,
     try:
         sub = os.path.join(d, f"cap_{os.getpid()}_{next(_CAP_IDS)}")
         with trace(sub):
-            out = run()
+            with annotation(WINDOW):
+                out = run()
         rep = parse_dir(sub, op_maps=op_maps, steps=steps,
                         iterlog_summary=iterlog_summary, top_k=top_k)
         rep["capture_dir"] = sub
@@ -301,7 +322,11 @@ def op_map_from_hlo(hlo_text: str) -> dict:
     for im in _HLO_OP_RE.finditer(hlo_text):
         tags = NAME_RE.findall(im.group(2))
         if tags:
-            ops[im.group(1)] = tags[-1]       # innermost scope wins
+            # Innermost scope wins, except that a phase tag stays: a
+            # kernel scope (lux.tiled.strip_scan) nested in a sharded
+            # engine's lux.*.compute must still count as compute.
+            phased = [t for t in tags if _phase_of(t)]
+            ops[im.group(1)] = (phased or tags)[-1]
     return {"module": m.group(1) if m else None, "ops": ops}
 
 
@@ -417,6 +442,8 @@ def parse_events(doc: dict, op_maps=None, steps=None,
     dev = {}                 # pid -> phase -> [(s, e)]
     host_regions = {}
     top = {}
+    window = None            # the longest WINDOW host span
+    extent = None            # (first start, last end) of every X event
     for ev in doc["traceEvents"]:
         if not isinstance(ev, dict):
             raise ProfileParseError(f"non-object trace event: {ev!r}")
@@ -435,8 +462,13 @@ def parse_events(doc: dict, op_maps=None, steps=None,
         if ts is None:
             raise ProfileParseError(f"X event {name!r} has no ts")
         dur = _num(ev, "dur", 0.0) or 0.0
+        extent = ((ts, ts + dur) if extent is None else
+                  (min(extent[0], ts), max(extent[1], ts + dur)))
         args = ev.get("args") or {}
         hlo_op = args.get("hlo_op")
+        if hlo_op is None and name == WINDOW:
+            if window is None or dur > window[1] - window[0]:
+                window = (ts, ts + dur)
         if hlo_op is None and isinstance(name, str) \
                 and NAME_RE.fullmatch(name):
             rec = host_regions.setdefault(
@@ -465,6 +497,10 @@ def parse_events(doc: dict, op_maps=None, steps=None,
     devices = {}
     tot_ex = tot_ov = 0.0
     span_lo, span_hi = None, None
+    # Idle is measured against the capture window, not the span from the
+    # first device op to the last: idle before and after counts too.
+    w0, w1 = window or extent or (0.0, 0.0)
+    window_us = w1 - w0
     for pid, d in dev.items():
         ex = merge_intervals(d["exchange"])
         co = merge_intervals(d["compute"])
@@ -474,6 +510,8 @@ def parse_events(doc: dict, op_maps=None, steps=None,
         ov_us = union_total(intersect_merged(ex, co))
         un_us = union_total(both)
         busy_us = union_total(busy)
+        busy_in_window = union_total(
+            intersect_merged(busy, [(w0, w1)]) if window_us > 0 else [])
         lo = min(s for s, _ in busy) if busy else 0.0
         hi = max(e for _, e in busy) if busy else 0.0
         span_us = hi - lo
@@ -489,8 +527,9 @@ def parse_events(doc: dict, op_maps=None, steps=None,
             "union_us": un_us,
             "busy_us": busy_us,
             "span_us": span_us,
-            "idle_frac": (min(max(1.0 - busy_us / span_us, 0.0), 1.0)
-                          if span_us > 0 else None),
+            "window_us": window_us,
+            "idle_frac": (1.0 - busy_in_window / window_us
+                          if window_us > 0 else None),
             "realized_hidden_frac": frac,
         }
         tot_ex += ex_us

@@ -16,10 +16,13 @@ path:
 - ``complete(name, dur_s, ...)`` — record a span retrospectively
   (queue-wait is only known at dequeue).
 
-Every span emits three things: a sync B/E pair on its own thread lane
-plus an async "b"/"e" pair keyed by trace-id in the Chrome trace
+Every live span emits four things: a sync B/E pair on its own thread
+lane plus an async "b"/"e" pair keyed by trace-id in the Chrome trace
 (obs/trace.py — Perfetto draws the request as one lane across threads),
-and a ``lux_span_seconds{span=...}`` histogram observation.
+a ``lux_span_seconds{span=...}`` histogram observation, and a
+``lux.<name>`` host span in a live ``jax.profiler`` capture
+(``prof.annotation``), on the same clock as the device's ops. Spans
+recorded after the fact (``complete``) cannot enter the profiler.
 
 Clock helpers live here too: LUX006 (analysis/rules.py) bans direct
 ``time.*`` clock reads in serve/ and engine/ so every latency number and
@@ -27,7 +30,8 @@ span shares one clock pair — ``clock()`` (perf_counter, durations and
 trace stamps) and ``monotonic()`` (deadlines, wall scheduling).
 
 Gated by ``LUX_SPANS`` (default on); when off, ``span`` is a
-pass-through and nothing is recorded. Pure stdlib; no jax.
+pass-through and nothing is recorded. Pure stdlib; jax only through
+``prof.annotation``, which never imports it.
 """
 
 from __future__ import annotations
@@ -43,10 +47,14 @@ from typing import Callable, Dict, List, Optional
 
 from ..utils import flags
 from ..utils.locks import make_lock
-from . import metrics, trace
+from . import metrics, prof, trace
 
 _TRACE_ID: "contextvars.ContextVar[Optional[str]]" = contextvars.ContextVar(
     "lux_trace_id", default=None
+)
+# The innermost live span's attrs, for ``set_attrs``.
+_ATTRS: "contextvars.ContextVar[Optional[dict]]" = contextvars.ContextVar(
+    "lux_span_attrs", default=None
 )
 _seq = itertools.count(1)
 
@@ -177,19 +185,32 @@ def span(name: str, **attrs):
     trace.begin(name, cat="span", args=dict(attrs, trace_id=tid) if attrs
                 else {"trace_id": tid})
     trace.async_begin(name, tid, cat="span", args=attrs or None)
+    late = {}
+    attrs_token = _ATTRS.set(late)
     try:
-        yield tid
+        with prof.annotation("lux." + name):
+            yield tid
     finally:
         t1 = clock()
+        _ATTRS.reset(attrs_token)
         trace.async_end(name, tid, cat="span")
-        trace.end(name, cat="span")
+        trace.end(name, cat="span", args=late or None)
         metrics.histogram(
             "lux_span_seconds", {"span": name}, buckets=SPAN_BUCKETS
         ).observe(t1 - t0)
-        _note_span(tid, name, t0, t1, attrs)
+        _note_span(tid, name, t0, t1, dict(attrs, **late))
         if root:
             _TRACE_ID.reset(token)
             _finish_trace(tid)
+
+
+def set_attrs(**attrs):
+    """Attach attrs known only once the work ran (an engine's iteration
+    counts) to the innermost live span of this context; a no-op outside
+    any span."""
+    late = _ATTRS.get()
+    if late is not None:
+        late.update(attrs)
 
 
 @contextlib.contextmanager
@@ -230,13 +251,24 @@ def complete(name: str, dur_s: float, end: Optional[float] = None,
 def open_trace():
     """Explicitly opened trace for callers that cannot scope the request
     in one ``with`` block (Session.submit returns a Future): returns
-    ``(trace_id, finish)``; call ``finish()`` when the request resolves.
-    Finishing twice (or racing a dropped record) is a no-op."""
+    ``(trace_id, finish)``; call ``finish()`` when the request resolves,
+    on any thread. Its profiler span ``lux.request`` runs from here to
+    the first ``finish()``. Finishing twice (or racing a dropped record)
+    is a no-op."""
     if not enabled():
         return None, lambda: None
     tid = new_trace_id()
     _begin_trace(tid)
-    return tid, lambda: _finish_trace(tid)
+    ann = prof.annotation("lux.request")
+    ann.__enter__()
+    once = threading.Lock()
+
+    def finish():
+        if once.acquire(blocking=False):
+            ann.__exit__(None, None, None)
+        _finish_trace(tid)
+
+    return tid, finish
 
 
 def activate(trace_id: Optional[str]):

@@ -344,6 +344,23 @@ def segment_minmax_blockmin(data, layout_arrays, head_segs, tail_segs,
     return red(red(head, tail), interior)
 
 
+def cumsum0(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive prefix sum along axis 0 in ``x``'s dtype: the same
+    reduce-window ``jnp.cumsum`` lowers to, built inline so its ops keep
+    the caller's ``jax.named_scope``. jax lowers ``cumsum`` as a separate
+    function whose ops lose the scope, so the device trace could not put
+    them down to the engine phase that runs them."""
+    n, nd = x.shape[0], x.ndim
+    if n == 0:
+        return x
+    return jax.lax.reduce_window(
+        x, jnp.zeros((), x.dtype), jax.lax.add,
+        window_dimensions=(n,) + (1,) * (nd - 1),
+        window_strides=(1,) * nd,
+        padding=((n - 1, 0),) + ((0, 0),) * (nd - 1),
+    )
+
+
 def segment_sum_by_rowptr(data: jnp.ndarray, row_ptr: jnp.ndarray) -> jnp.ndarray:
     """Sum sorted segments given CSC offsets, scatter-free.
 

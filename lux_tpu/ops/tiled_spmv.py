@@ -55,6 +55,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from lux_tpu.graph.graph import Graph
+from lux_tpu.obs.prof import region
+from lux_tpu.ops.segment import cumsum0
 
 BLOCK = 128
 # Scan-chunk default for the tail body: measured sweet spot on v5e
@@ -879,7 +881,7 @@ def _transpose_cumsum(contrib: jnp.ndarray):
     s = _subs_per_chunk(r)
     cs = c // s
     zt = contrib.reshape(s, cs, r).transpose(1, 0, 2).reshape(cs, BLOCK)
-    z = jnp.cumsum(zt, axis=0)
+    z = cumsum0(zt)
     zrows = jnp.concatenate([jnp.zeros((1, BLOCK), jnp.float32), z])
     return zrows, z[-1].reshape(s, r)
 
@@ -988,40 +990,44 @@ def strip_level_spmv(x2d: jnp.ndarray, lev: DeviceLevel, nrb: int) -> jnp.ndarra
         # _dd_prefix on the chunk totals if large r=128 levels become a
         # supported config.
         def body(carry, chunk):
-            s_loc = jnp.cumsum(contrib_of(chunk), axis=0)
+            s_loc = cumsum0(contrib_of(chunk))
             out = jnp.concatenate(
                 [jnp.zeros((1, r), jnp.float32), s_loc]
             )
             return carry + s_loc[-1], (out, carry)
 
-        carry, (z, pk) = jax.lax.scan(
-            body, jnp.zeros((r,), jnp.float32), (lev.strips, lev.cols)
-        )
-        lf = jnp.concatenate(
-            [z.reshape(-1, BLOCK), jnp.zeros((1, BLOCK), jnp.float32)]
-        )
-        pp = jnp.concatenate([pk, carry[None]])          # (K+1, 128)
-        _warn_big_table(lf.shape[0], f"strip level r={BLOCK}")
-        gl = lf[lev.bnd_row].reshape(-1)
-        gp = pp[lev.bnd_grp].reshape(-1)
-        return (gp[r:] - gp[:-r]) + (gl[r:] - gl[:-r])
+        with region("lux.tiled.strip_scan"):
+            carry, (z, pk) = jax.lax.scan(
+                body, jnp.zeros((r,), jnp.float32), (lev.strips, lev.cols)
+            )
+        with region("lux.tiled.strip_boundary"):
+            lf = jnp.concatenate(
+                [z.reshape(-1, BLOCK), jnp.zeros((1, BLOCK), jnp.float32)]
+            )
+            pp = jnp.concatenate([pk, carry[None]])      # (K+1, 128)
+            _warn_big_table(lf.shape[0], f"strip level r={BLOCK}")
+            gl = lf[lev.bnd_row].reshape(-1)
+            gp = pp[lev.bnd_grp].reshape(-1)
+            return (gp[r:] - gp[:-r]) + (gl[r:] - gl[:-r])
 
     def body(_, chunk):
         zrows, totals = _transpose_cumsum(contrib_of(chunk))
         return 0, (zrows, totals)
 
-    _, (z, totals) = jax.lax.scan(body, 0, (lev.strips, lev.cols))
-    flatz = jnp.concatenate(
-        [z.reshape(-1, BLOCK), jnp.zeros((1, BLOCK), jnp.float32)]
-    )
-    gl = zstream_extract(flatz, r, lev.segs, lev.bnd_row, lev.bnd_grp)
-    y = gl[r:] - gl[:-r]
-    ph, pl = _dd_prefix(totals.reshape(-1, r))
-    corr = (
-        (ph[lev.xing_s1] - ph[lev.xing_s0])
-        + (pl[lev.xing_s1] - pl[lev.xing_s0])
-    )
-    return y.at[lev.xing_idx].add(corr.reshape(-1))
+    with region("lux.tiled.strip_scan"):
+        _, (z, totals) = jax.lax.scan(body, 0, (lev.strips, lev.cols))
+    with region("lux.tiled.strip_boundary"):
+        flatz = jnp.concatenate(
+            [z.reshape(-1, BLOCK), jnp.zeros((1, BLOCK), jnp.float32)]
+        )
+        gl = zstream_extract(flatz, r, lev.segs, lev.bnd_row, lev.bnd_grp)
+        y = gl[r:] - gl[:-r]
+        ph, pl = _dd_prefix(totals.reshape(-1, r))
+        corr = (
+            (ph[lev.xing_s1] - ph[lev.xing_s0])
+            + (pl[lev.xing_s1] - pl[lev.xing_s0])
+        )
+        return y.at[lev.xing_idx].add(corr.reshape(-1))
 
 
 def lane_select_tail_sums(
@@ -1048,30 +1054,34 @@ def lane_select_tail_sums(
 
     def body(_, chunk):
         sb, lane = chunk
-        rows = x2d[sb]                                  # (C, 128) row gather
-        v = jnp.where(
-            lane.astype(jnp.int32)[:, None] == iota[None, :], rows, 0.0
-        ).sum(axis=1)                                   # (C,)
-        zrows, totals = _transpose_cumsum(v[:, None])
+        with region("lux.tiled.tail_gather"):
+            rows = x2d[sb]                              # (C, 128) row gather
+            v = jnp.where(
+                lane.astype(jnp.int32)[:, None] == iota[None, :], rows, 0.0
+            ).sum(axis=1)                               # (C,)
+        with region("lux.tiled.tail_zstream"):
+            zrows, totals = _transpose_cumsum(v[:, None])
         return 0, (zrows, totals)
 
     _, (z, totals) = jax.lax.scan(body, 0, (tail_sb, tail_lane))
-    flatz = jnp.concatenate(
-        [z.reshape(-1, BLOCK), jnp.zeros((1, BLOCK), jnp.float32)]
-    )
-    gl = zstream_extract(flatz, 1, segs, bnd_row, bnd_grp)
-    y = gl[1:] - gl[:-1]
-    ph, pl = _dd_prefix(totals.reshape(-1, 1))
-    corr = (
-        (ph[xing_s1] - ph[xing_s0]) + (pl[xing_s1] - pl[xing_s0])
-    )
-    return y.at[xing_idx].add(corr.reshape(-1))
+    with region("lux.tiled.tail_boundary"):
+        flatz = jnp.concatenate(
+            [z.reshape(-1, BLOCK), jnp.zeros((1, BLOCK), jnp.float32)]
+        )
+        gl = zstream_extract(flatz, 1, segs, bnd_row, bnd_grp)
+        y = gl[1:] - gl[:-1]
+        ph, pl = _dd_prefix(totals.reshape(-1, 1))
+        corr = (
+            (ph[xing_s1] - ph[xing_s0]) + (pl[xing_s1] - pl[xing_s0])
+        )
+        return y.at[xing_idx].add(corr.reshape(-1))
 
 
 def vals_to_x2d(vals: jnp.ndarray, dh: DeviceHybrid) -> jnp.ndarray:
     """(nv,) values → (nvb, 128) padded gather operand."""
     pad = dh.nvb * BLOCK - vals.shape[0]
-    return jnp.pad(vals, (0, pad)).reshape(dh.nvb, BLOCK)
+    with region("lux.tiled.permute"):
+        return jnp.pad(vals, (0, pad)).reshape(dh.nvb, BLOCK)
 
 
 def strips_sum(x2d: jnp.ndarray, dh: DeviceHybrid, nv: int) -> jnp.ndarray:

@@ -70,15 +70,12 @@ class ResultCache:
 
     def get(self, key: Hashable) -> Optional[Any]:
         with self._lock:
-            if key in self._d:
-                self._d.move_to_end(key)
-                self._hits.inc()
-                hit, out = True, self._d[key]
-            else:
+            if key not in self._d:
                 self._misses.inc()
-                hit, out = False, None
-        spans.complete("serve.cache.get", 0.0, hit=hit)
-        return out
+                return None
+            self._d.move_to_end(key)
+            self._hits.inc()
+            return self._d[key]
 
     def put(self, key: Hashable, value: Any) -> None:
         with spans.span("serve.cache.put"):

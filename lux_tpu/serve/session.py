@@ -83,9 +83,10 @@ def _host_values(ex, state) -> np.ndarray:
     """Host-side per-vertex values from an executor state: sharded
     executors unpad their stacked shards (``gather_values``); flat ones
     hand back ``state.values`` directly."""
-    if hasattr(ex, "gather_values"):
-        return np.asarray(ex.gather_values(state))
-    return np.asarray(state.values)
+    with spans.span("serve.host_values"):
+        if hasattr(ex, "gather_values"):
+            return np.asarray(ex.gather_values(state))
+        return np.asarray(state.values)
 
 
 class Session:
@@ -964,8 +965,7 @@ class Session:
             try:
                 with self._watched(key):
                     faults.point("serve.engine.execute")
-                    with prof.region("lux.serve.execute"):
-                        out = fn()
+                    out = fn()
             except ServeError:
                 raise             # shed/typed errors are not engine faults
             except Exception as e:
@@ -1070,6 +1070,8 @@ class Session:
                 with spans.span("serve.engine", app="sssp", engine="push",
                                 lanes=1):
                     state, iters = ex.run(start=roots[0])
+                    spans.set_attrs(iters=int(iters),
+                                    sparse_iters=int(ex.sparse_iters))
                     return [_host_values(ex, state)], int(iters)
         else:
             key = self._engine_key(

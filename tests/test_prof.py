@@ -125,6 +125,26 @@ def test_multi_device_streams_stay_separate():
     assert rep["realized_hidden_frac"] == 0.0
 
 
+def test_idle_counts_gaps_before_first_and_after_last_op():
+    # Capture window 0..1000 us; the device is busy 300..400 and
+    # 500..600, so idle is 0.8 of the window (the first-op-to-last-op
+    # span would read 1/3).
+    events = [
+        ev(prof.WINDOW, 0, 1000),
+        ev("fusion.2", 300, 100, hlo_op="fusion.2"),
+        ev("fusion.2", 500, 100, hlo_op="fusion.2"),
+    ]
+    d = parse(events)["devices"]["1"]
+    assert d["window_us"] == 1000 and d["span_us"] == 300
+    assert d["idle_frac"] == pytest.approx(0.8)
+    # Without the window span the trace's whole extent is the window:
+    # a host span before the first op counts as captured idle time.
+    events[0] = ev("lux.serve.engine", 0, 700)
+    d = parse(events)["devices"]["1"]
+    assert d["window_us"] == 700
+    assert d["idle_frac"] == pytest.approx(1 - 200 / 700)
+
+
 def test_missing_dur_counts_as_instant():
     d = parse([
         ev("all-gather.1", 0, 10, hlo_op="all-gather.1"),
@@ -148,13 +168,13 @@ def test_host_regions_never_join_device_unions():
     # A host TraceAnnotation span covering the whole window must not
     # manufacture overlap (async dispatch!): device overlap stays 0.
     rep = parse([
-        ev("lux.serve.execute", 0, 100),          # host span, no hlo_op
+        ev("lux.serve.engine", 0, 100),           # host span, no hlo_op
         ev("all-gather.1", 0, 10, hlo_op="all-gather.1"),
         ev("fusion.2", 20, 10, hlo_op="fusion.2"),
     ])
     assert rep["devices"]["1"]["overlap_us"] == 0
-    assert rep["host_regions"]["lux.serve.execute"]["count"] == 1
-    assert "lux.serve.execute" in rep["tags"]
+    assert rep["host_regions"]["lux.serve.engine"]["count"] == 1
+    assert "lux.serve.engine" in rep["tags"]
 
 
 def test_non_lux_host_spans_ignored():

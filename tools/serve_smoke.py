@@ -225,7 +225,7 @@ def main() -> int:
         full = {
             tid: names for tid, names in chains.items()
             if chain_want <= names
-            and names & {"serve.cache.put", "serve.cache.get"}
+            and "serve.cache.put" in names
         }
         assert full, (
             f"no single trace-id covers {sorted(chain_want)} + cache; "

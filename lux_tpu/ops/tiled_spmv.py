@@ -7,10 +7,15 @@ Measured TPU v5e rates dictate the design:
 - arbitrary 1-element gather: ~8.5 ns/edge (scalarized — the TPU VPU has
   no fine-grained HBM access; this is the reference's atomicAdd/gather
   world and the thing to design away);
-- 128-wide **row** gather: ~0.9 ns/row (~540 GB/s — full bandwidth);
+- 128-wide **row** gather from the (nvb, 128) f32 value table at RMAT22:
+  1.5 ns/row when XLA keeps the table in VMEM, 8.3-9.4 ns/row when it
+  leaves it in HBM (one random 512 B HBM read per row). XLA's
+  memory-space assignment picks per loop; in the fused step it gave the
+  strip loop VMEM and the tail loop HBM;
 - int8 strip matmul: streams at ~520 GB/s through the MXU.
 
-So the only fast irregular primitive is "fetch an aligned 128-block".
+So the only fast irregular primitive is "fetch an aligned 128-block",
+and from VMEM.
 Every edge is served by one of two such layouts:
 
 1. **Strip levels** (:class:`StripLevel`): after degree-sort relabeling,
@@ -32,13 +37,15 @@ Every edge is served by one of two such layouts:
    rows of ``jax.ops.segment_sum`` that ran at scalar rate
    (measured 117 ms -> ~3 ms on RMAT22).
 
-2. **Lane-select tail**: a leftover edge costs one 128-wide row gather
+2. **Lane-select tail**: a leftover edge costs one 128-wide row read
    of its source block plus an on-the-fly one-hot lane selection
-   (``where(lane == iota, row, 0).sum()``) — pure VPU, *exact* f32, and
-   ~512 HBM bytes/edge instead of the 4.4 KB-equivalent of a scalar
-   gather. Edges stay CSC-sorted so the per-destination reduction is
-   the scatter-free Z-stream boundary diff at the static
-   ``tail_row_ptr`` boundaries.
+   (``where(lane == iota, row, 0).sum()``) — pure VPU, *exact* f32.
+   On a TPU, where the table fits, the rows come from a Pallas kernel
+   that holds the table whole in VMEM
+   (:mod:`~lux_tpu.ops.lane_select_kernel`): the tail no longer depends
+   on where XLA places the table. Edges stay CSC-sorted so the
+   per-destination reduction is the scatter-free Z-stream boundary diff
+   at the static ``tail_row_ptr`` boundaries.
 
 This layout has no reference counterpart — it is what "gather" means on
 hardware whose only irregular-access engines are aligned block DMA and
@@ -56,12 +63,20 @@ import numpy as np
 
 from lux_tpu.graph.graph import Graph
 from lux_tpu.obs.prof import region
+from lux_tpu.ops.lane_select_kernel import (
+    lane_select_pallas,
+    lane_select_ref,
+    vmem_table_fits,
+)
 from lux_tpu.ops.segment import cumsum0
 
 BLOCK = 128
-# Scan-chunk default for the tail body: measured sweet spot on v5e
-# (PERF_NOTES.md chunk sweep — ~10% faster than 2^19; smaller chunks pipeline
-# the gathers better).
+# Scan-chunk default for the tail body. Where the VMEM-table kernel
+# serves the tail, the chunk only sizes the Z-stream emit (the kernel
+# has its own grid step; 4096 to 65536 edges moved an RMAT22 iteration
+# by under 0.3 ms on v5e). The row-gather body, kept where the table is
+# too large for VMEM, last swept best at 2^17 (PERF_NOTES.md chunk
+# sweep, timed with gather, select and cumsum in one scan).
 DEFAULT_CHUNK_TAIL = 1 << 17
 # Strip scan chunk default: strips prefer LARGER chunks than the tail
 # (measured sweep: 13.6 ms at 2^15 vs 15.9 at 2^14 vs 31 at 2^11 on the
@@ -1040,30 +1055,57 @@ def lane_select_tail_sums(
     xing_s0: jnp.ndarray,
     xing_s1: jnp.ndarray,
     segs,
+    use_pallas: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Per-destination sums of tail-edge source values, fused.
 
-    Each tail edge costs one 128-wide row gather of its source block plus
+    Each tail edge costs one 128-wide row read of its source block plus
     an on-the-fly one-hot lane selection (exact f32). The per-destination
     reduction is the Z-stream boundary diff at the static
     ``tail_row_ptr`` boundaries (r=1) + the sparse double-single P
     correction. Pad edges past the real tail length land after the last
     boundary and are never read. Returns (nv,) f32.
+
+    Where the table fits VMEM (:func:`vmem_table_fits`), a TPU lowering
+    reads the rows with the Pallas kernel of
+    :mod:`~lux_tpu.ops.lane_select_kernel`, which holds ``x2d`` in VMEM,
+    and the scan emits the Z-stream from its values; elsewhere the scan
+    body row-gathers from ``x2d`` itself. Both give the same values.
+    ``use_pallas`` forces one form (``interpret`` runs the kernel in
+    Pallas interpret mode, off the TPU).
     """
-    iota = jnp.arange(BLOCK, dtype=jnp.int32)
 
-    def body(_, chunk):
-        sb, lane = chunk
+    def gather_scan(x2d, tail_sb, tail_lane):
+        def body(_, chunk):
+            sb, lane = chunk
+            with region("lux.tiled.tail_gather"):
+                v = lane_select_ref(x2d, sb, lane)      # (C,)
+            with region("lux.tiled.tail_zstream"):
+                return 0, _transpose_cumsum(v[:, None])
+
+        return jax.lax.scan(body, 0, (tail_sb, tail_lane))[1]
+
+    def vmem_scan(x2d, tail_sb, tail_lane):
         with region("lux.tiled.tail_gather"):
-            rows = x2d[sb]                              # (C, 128) row gather
-            v = jnp.where(
-                lane.astype(jnp.int32)[:, None] == iota[None, :], rows, 0.0
-            ).sum(axis=1)                               # (C,)
-        with region("lux.tiled.tail_zstream"):
-            zrows, totals = _transpose_cumsum(v[:, None])
-        return 0, (zrows, totals)
+            v = lane_select_pallas(
+                x2d, tail_sb.reshape(-1), tail_lane.reshape(-1),
+                interpret=interpret)
 
-    _, (z, totals) = jax.lax.scan(body, 0, (tail_sb, tail_lane))
+        def body(_, vc):
+            with region("lux.tiled.tail_zstream"):
+                return 0, _transpose_cumsum(vc[:, None])
+
+        return jax.lax.scan(body, 0, v.reshape(tail_sb.shape))[1]
+
+    if tail_sb.size == 0:
+        use_pallas = False
+    if use_pallas is None and vmem_table_fits(x2d):
+        z, totals = jax.lax.platform_dependent(
+            x2d, tail_sb, tail_lane, tpu=vmem_scan, default=gather_scan)
+    else:
+        scan = vmem_scan if use_pallas else gather_scan
+        z, totals = scan(x2d, tail_sb, tail_lane)
     with region("lux.tiled.tail_boundary"):
         flatz = jnp.concatenate(
             [z.reshape(-1, BLOCK), jnp.zeros((1, BLOCK), jnp.float32)]

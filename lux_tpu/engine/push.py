@@ -119,18 +119,43 @@ def _sparse_budgets(nv: int, ne: int, queue_frac: int, edge_budget_frac: int):
     return nv // queue_frac + 128, max(ne // edge_budget_frac, 1024)
 
 
-def _make_tiers(queue_cap: int, edge_budget: int):
+# Cost of one sparse-tier iteration against one blocked dense sweep,
+# in units of the sweep's cost an edge, c: a tier of E expansion slots
+# costs SPARSE_SCAN_COST * nv (the frontier scan that fills its queue)
+# + SPARSE_SLOT_COST * E, the sweep costs ne. One TPU v5e, Graph500
+# scale 22, fused iterations from 45 frontiers (tools/push_tiers.py):
+# the sweep 208.4 ms (c = 3.11 ns an edge); tiers of E = 131,072 /
+# 1,048,576 / 8,388,608 slots 47.4 / 110.4 / 808.9 ms, fitted as a
+# 24.5 ms scan (5.84 ns a vertex, s/c = 1.88) + 93.3 ns a slot
+# (a/c = 30.1).
+SPARSE_SCAN_COST = 1.9
+SPARSE_SLOT_COST = 30.0
+
+
+def _make_tiers(queue_cap: int, edge_budget: int, nv: int, ne: int,
+                blocked_dense: bool):
     """Ascending (queue, edge budget) size tiers derived from the full
     budgets. Shared by both executors (like _sparse_budgets) so a policy
     tweak cannot silently diverge them: per iteration the smallest
     adequate tier serves, so a near-fixpoint frontier of a few vertices
-    does not pay the full ne/8 expansion + scatter (~1 s/iter measured
-    at RMAT22)."""
+    does not pay the full ne/8 expansion + scatter.
+
+    Where the dense branch is the blocked sweep, a tier is kept only if
+    one iteration of it costs less than one sweep over the ``ne`` edges
+    (the cost model above, per part for the sharded executor); frontiers
+    that would have needed a dropped tier go dense. The plain dense
+    branch costs an order of magnitude more an edge, so its ladder keeps
+    every tier."""
     tiers = []
     for div in (64, 8, 1):
         t = (max(queue_cap // div, 256), max(edge_budget // div, 1024))
-        if t not in tiers:
-            tiers.append(t)
+        if t in tiers:
+            continue
+        if blocked_dense and (
+            SPARSE_SCAN_COST * nv + SPARSE_SLOT_COST * t[1] >= ne
+        ):
+            continue
+        tiers.append(t)
     return tiers
 
 
@@ -339,7 +364,12 @@ class PushExecutor:
             self.queue_cap, self.edge_budget = _sparse_budgets(
                 int(graph.nv), int(graph.ne), queue_frac, edge_budget_frac
             )
-            self.tiers = _make_tiers(self.queue_cap, self.edge_budget)
+            self.tiers = _make_tiers(
+                self.queue_cap, self.edge_budget, int(graph.nv),
+                int(graph.ne), self.blocked_dense,
+            )
+            self.sparse = bool(self.tiers)
+        if self.sparse:
             from lux_tpu.engine.pull import _edge_index_dtype
 
             csr = graph.csr()
@@ -1086,7 +1116,12 @@ class ShardedPushExecutor:
             self.queue_cap, self.edge_budget = _sparse_budgets(
                 self.sg.max_nv, self.sg.max_ne, queue_frac, edge_budget_frac
             )
-            self.tiers = _make_tiers(self.queue_cap, self.edge_budget)
+            self.tiers = _make_tiers(
+                self.queue_cap, self.edge_budget, self.sg.max_nv,
+                self.sg.max_ne, self.blocked_dense,
+            )
+            self.sparse = bool(self.tiers)
+        if self.sparse:
             prp, pdst, pw = self.sg.build_push_csr()
             self._dg["push_row_ptr"] = put(prp)
             self._dg["push_dst_local"] = put(pdst)

@@ -5,7 +5,12 @@ import numpy as np
 import pytest
 
 from lux_tpu.engine.check import check, count_violations
-from lux_tpu.engine.push import PushExecutor, ShardedPushExecutor
+from lux_tpu.engine.push import (
+    PushExecutor,
+    ShardedPushExecutor,
+    _make_tiers,
+    _sparse_budgets,
+)
 from lux_tpu.graph import generate
 from lux_tpu.models.components import ConnectedComponents, reference_components
 from lux_tpu.models.sssp import SSSP, reference_sssp
@@ -317,3 +322,96 @@ def test_sparse_path_graph_long_chain():
     np.testing.assert_array_equal(
         np.asarray(state.values), np.arange(1100, dtype=np.uint32)
     )
+
+
+def test_tier_ladder_drops_tiers_dearer_than_blocked_sweep():
+    # Graph500 scale 22 with the default fractions: the ne/8 tier costs
+    # more an iteration than the blocked dense sweep, the two smaller
+    # tiers less.
+    nv, ne = 1 << 22, 1 << 26
+    budgets = _sparse_budgets(nv, ne, 16, 8)
+    full = [(4098, 131072), (32784, 1048576), (262272, 8388608)]
+    assert _make_tiers(*budgets, nv, ne, blocked_dense=False) == full
+    assert _make_tiers(*budgets, nv, ne, blocked_dense=True) == full[:2]
+
+
+@pytest.mark.parametrize("build,tiers", [
+    (lambda: PushExecutor(generate.path_graph(1100), SSSP(), queue_frac=1),
+     [(256, 1024), (1228, 1024)]),
+    (lambda: PushExecutor(generate.gnp(2000, 16000, seed=21), SSSP(),
+                          queue_frac=4, edge_budget_frac=2),
+     [(256, 1024), (628, 8000)]),
+    (lambda: ShardedPushExecutor(generate.path_graph(1100), SSSP(),
+                                 mesh=make_mesh(4), queue_frac=1),
+     [(256, 1024), (408, 1024)]),
+], ids=["path", "gnp", "sharded-path"])
+def test_plain_dense_ladder_keeps_every_tier(build, tiers):
+    # The plain gather/scatter dense branch keeps the whole ladder.
+    ex = build()
+    assert not ex.blocked_dense
+    assert ex.tiers == tiers
+
+
+def _levels(g, dist):
+    """(frontier size, its out-edges) of each BFS level of ``dist``."""
+    od = g.out_degrees
+    return [(int((dist == k).sum()), int(od[dist == k].sum()))
+            for k in range(int(dist[dist < g.nv].max()) + 1)]
+
+
+def _adequate(tiers, cnt, out_edges):
+    return any(cnt <= q and out_edges <= e for q, e in tiers)
+
+
+def test_blocked_dense_ladder_sssp_parity():
+    # ne = 2^18 takes the blocked dense path by default. From the hub,
+    # two levels fit only the dropped ne/8 tier: they go dense, and the
+    # fixpoint, its iteration count and the other levels' sparse
+    # iterations are unchanged.
+    g = generate.rmat(14, 16, seed=61)
+    ex = PushExecutor(g, SSSP())
+    assert ex.blocked_dense
+    full = _make_tiers(ex.queue_cap, ex.edge_budget, g.nv, g.ne,
+                       blocked_dense=False)
+    dropped = [t for t in full if t not in ex.tiers]
+    assert dropped == full[-1:]
+    want = reference_sssp(g, start=0)
+    levels = _levels(g, want)
+    assert any(_adequate(dropped, *lv) and not _adequate(ex.tiers, *lv)
+               for lv in levels)
+    state, iters = ex.run(start=0)
+    dense, dense_iters = PushExecutor(g, SSSP(), sparse=False).run(start=0)
+    np.testing.assert_array_equal(np.asarray(state.values), want)
+    np.testing.assert_array_equal(np.asarray(dense.values), want)
+    assert iters == dense_iters == len(levels)
+    assert ex.sparse_iters == sum(_adequate(ex.tiers, *lv) for lv in levels)
+
+
+def test_blocked_dense_ladder_cc_parity():
+    g = generate.undirected(generate.rmat(13, 16, seed=63))
+    ex = PushExecutor(g, ConnectedComponents())
+    assert ex.blocked_dense and ex.sparse
+    state, iters = ex.run()
+    dense, dense_iters = PushExecutor(
+        g, ConnectedComponents(), sparse=False).run()
+    np.testing.assert_array_equal(
+        np.asarray(state.values), np.asarray(dense.values))
+    np.testing.assert_array_equal(
+        np.asarray(state.values), reference_components(g))
+    assert iters == dense_iters
+
+
+def test_sharded_blocked_dense_ladder_parity():
+    # The sharded executor applies the same rule to its per-part shapes.
+    g = generate.rmat(14, 16, seed=61)
+    ex = ShardedPushExecutor(g, SSSP(), mesh=make_mesh(2))
+    assert ex.blocked_dense
+    nv, ne = ex.sg.max_nv, ex.sg.max_ne
+    assert ex.tiers == _make_tiers(ex.queue_cap, ex.edge_budget, nv, ne,
+                                   blocked_dense=True)
+    full = _make_tiers(ex.queue_cap, ex.edge_budget, nv, ne,
+                       blocked_dense=False)
+    assert len(ex.tiers) < len(full)
+    state, _ = ex.run(start=0)
+    np.testing.assert_array_equal(
+        ex.gather_values(state), reference_sssp(g, start=0))

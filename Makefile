@@ -2,7 +2,7 @@
 # library compiles itself on first use into the source-hash cache — the
 # `native` target just runs that one real build path eagerly).
 
-.PHONY: all native lint lint-ir lint-threads lint-exchange lint-programs lint-memory mem-smoke plan-check test verify bench bench-gate obs-smoke serve-smoke serve-obs serve-bench serve-slo merge-smoke snapshot-smoke serve-sharded-smoke gas-smoke gas-sharded-smoke exchange-smoke prof-smoke ledger-smoke tune-smoke race-stress chaos-stress clean
+.PHONY: all native lint lint-ir lint-threads lint-exchange lint-programs lint-memory mem-smoke test verify bench bench-gate obs-smoke serve-smoke serve-obs serve-bench serve-slo snapshot-smoke serve-sharded-smoke gas-smoke gas-sharded-smoke exchange-smoke prof-smoke ledger-smoke tune-smoke race-stress chaos-stress clean
 
 all: native
 
@@ -51,13 +51,10 @@ lint-memory:
 mem-smoke:
 	env JAX_PLATFORMS=cpu python tools/memck_smoke.py
 
-plan-check:
-	python tools/plan_check.py
-
 test:
 	python -m pytest tests/ -q
 
-verify: lint lint-ir lint-threads lint-exchange lint-programs lint-memory mem-smoke plan-check test serve-obs snapshot-smoke serve-sharded-smoke gas-smoke gas-sharded-smoke exchange-smoke prof-smoke ledger-smoke tune-smoke race-stress chaos-stress bench-gate
+verify: lint lint-ir lint-threads lint-exchange lint-programs lint-memory mem-smoke test serve-obs snapshot-smoke serve-sharded-smoke gas-smoke gas-sharded-smoke exchange-smoke prof-smoke ledger-smoke tune-smoke race-stress chaos-stress bench-gate
 
 bench:
 	python bench.py
@@ -79,9 +76,6 @@ serve-smoke:
 # and a flight.v1 postmortem on an injected deadline miss.
 serve-obs:
 	python tools/serve_smoke.py
-
-merge-smoke:
-	python tools/merge_smoke.py
 
 # Dynamic-graph acceptance: hot-swap under in-flight traffic, FIFO drain
 # barrier, incremental cache refresh, zero recompiles, one swap trace-id.

@@ -37,10 +37,7 @@ Knobs (env): LUX_BENCH_SCALE (22), LUX_BENCH_EF (16), LUX_BENCH_ITERS
 (50), LUX_BENCH_CACHE (.bench_cache), LUX_BENCH_LAYOUT (tiled|flat),
 LUX_BENCH_LEVELS ("8/2"), LUX_BENCH_TILE_MB (8192), LUX_BENCH_SUITE
 (1; 0 = headline only), LUX_BENCH_DEADLINE (480 — total seconds of
-wall clock after which remaining suite items are skipped),
-LUX_GROUPED_TAIL (0; 1 = tiled layout runs the source-block-grouped
-merge-network tail instead of lane-select — see PERF_NOTES.md round-5 and
-`make merge-smoke`).
+wall clock after which remaining suite items are skipped).
 
 ``--profile``: wrap the headline run in a device-timeline capture
 window (obs/prof.py) under LUX_PROF_DIR (default

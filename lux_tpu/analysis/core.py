@@ -150,8 +150,7 @@ class LintReport:
     results: List[FileResult]
     elapsed_s: float
     # The AST tier reports "luxlint.v1"; the jaxpr tier (analysis/ir.py)
-    # and the plan-artifact tier (analysis/planck.py) stamp their own
-    # schemas so one grep distinguishes which pass produced a line.
+    # and the artifact tiers (exchck, tuneck) stamp their own schemas so one grep distinguishes which pass produced a line.
     schema: str = "luxlint.v1"
 
     @property
@@ -171,7 +170,7 @@ class LintReport:
         return not self.findings and not self.errors
 
     def summary(self) -> dict:
-        """One-line greppable summary payload (the merge_smoke idiom)."""
+        """One-line greppable summary payload."""
         by_rule: Dict[str, int] = {}
         for f in self.findings:
             by_rule[f.rule] = by_rule.get(f.rule, 0) + 1

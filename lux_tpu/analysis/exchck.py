@@ -41,9 +41,9 @@ sampling:
   rows), and the advertised frontier bytes must re-derive from
   ``P * (P-1) * slots * frontier_row_bytes``.
 
-numpy + stdlib only, mirroring planck.py: plans are host arrays and a
-verifier must not drag in jax. The IR half of the tier (LUX404-406,
-dependence-walk rules over the traced step) lives in analysis/ir.py.
+numpy + stdlib only: plans are host arrays and a verifier must not
+drag in jax. The IR half of the tier (LUX404-406, dependence-walk
+rules over the traced step) lives in analysis/ir.py.
 """
 
 from __future__ import annotations
@@ -62,10 +62,9 @@ from lux_tpu.analysis.core import FileResult, Finding, LintReport
 EXCHANGE_SCHEMA = "luxlint-exchange.v1"
 
 # Mirror of the artifact format (graph/partition.EXCHANGE_PLAN_ARRAYS /
-# EXCHANGE_PLAN_FORMAT). Duplicated on purpose, like planck's mirror of
-# the grouped-plan format: this module must verify saved artifacts from
-# a cold jax-free interpreter. tests/test_exchck.py asserts the two
-# stay identical.
+# EXCHANGE_PLAN_FORMAT). Duplicated on purpose: this module must verify
+# saved artifacts from a cold jax-free interpreter. tests/test_exchck.py
+# asserts the two stay identical.
 EXCH_ARRAYS = ("counts", "send_units", "recv_pos")
 EXCH_FORMAT = 1
 

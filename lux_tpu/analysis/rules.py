@@ -11,10 +11,10 @@ code previously only promised in prose:
 - LUX002 recompile-hygiene: jitted steps must donate their buffer
   argument (else HBM holds two copies) and jitted callables must not be
   fed bare Python scalars (each distinct value retraces).
-- LUX003 kernel-shape-contract: Pallas BlockSpecs must honor the plan
-  layout rules from ops/merge_tail_plan.py — 128-lane blocks, rows in
-  Mosaic 8-row units (or single-row scalar-prefetch form), int8 code
-  planes, int32 row indices.
+- LUX003 kernel-shape-contract: Pallas BlockSpecs must honor the TPU
+  block layout — 128-lane blocks, rows in Mosaic 8-row units (or
+  single-row scalar-prefetch form), int8 code planes, int32 row
+  indices.
 - LUX004 env-flag-registry: every ``LUX_*`` key read anywhere must be
   declared in lux_tpu/utils/flags.py.
 - LUX005 direct-env-read: lux_tpu code reads LUX_* knobs through the
@@ -287,7 +287,7 @@ class KernelShapeContract(Rule):
     title = "kernel-shape-contract"
     doc = ("Pallas BlockSpecs: 128-lane blocks, rows 1 or a multiple of "
            "8; kernel dtype contract: int8 code planes, int32 row "
-           "indices (ops/merge_tail_plan.py layout rules)")
+           "indices (Mosaic's TPU block layout)")
 
     _CODE_DTYPES = {"int8", "int32"}   # codes upcast to int32 in-kernel
     _ROW_DTYPES = {"int32"}

@@ -3,7 +3,7 @@
 A tune artifact is the configuration a serving process will trust
 blindly at warmup — a corrupted, hand-edited, or stale one must be
 rejected offline, from the artifact alone, before anything builds
-engines under it. Like planck for grouped-tail plans:
+engines under it. The rules:
 
 - LUX501 structure: schema/id/key/key_string/score/score_table/tuner
   shapes match the tuneconf.v1 contract.
@@ -44,8 +44,6 @@ TUNE_SCHEMA = "luxlint-tune.v1"
 
 _ID_RE = re.compile(r"^tune-[0-9a-f]{12}$")
 _MESH_RE = re.compile(r"^\d+(x\d+)*$")
-_BOOLISH = frozenset({"", "0", "1", "true", "false", "yes", "no",
-                      "on", "off"})
 
 __all__ = ["TUNE_SCHEMA", "TuneRule", "all_tune_rules", "verify_artifact",
            "verify_artifact_paths"]
@@ -150,10 +148,6 @@ class TuneKnobDomains(TuneRule):
                 if not (0.0 < x <= 1.0):
                     yield self.finding(
                         path, row, f"{name}={x} outside (0, 1]")
-            elif name == "LUX_GROUPED_TAIL" \
-                    and v.strip().lower() not in _BOOLISH:
-                yield self.finding(
-                    path, row, f"LUX_GROUPED_TAIL={v!r} not boolean")
         hi = config.get("LUX_GAS_DENSITY_HI")
         lo = config.get("LUX_GAS_DENSITY_LO")
         if hi is not None and lo is not None:

@@ -39,10 +39,6 @@ from lux_tpu.obs import (
     spans,
 )
 from lux_tpu.utils.timing import Timer
-from lux_tpu.ops.merge_tail_kernel import (
-    DeviceGroupedTail,
-    grouped_tail_enabled,
-)
 from lux_tpu.ops.tiled_spmv import (
     DEFAULT_CHUNK_STRIPS,
     DEFAULT_CHUNK_TAIL,
@@ -70,19 +66,16 @@ def get_cached_plan(
     budget_bytes: int = 8 << 30,
     log=None,
     cap: int = 15,
-    pack: Optional[bool] = None,
 ) -> HybridPlan:
     """Load the hybrid plan cached at ``path`` (validating it against the
     graph), else plan and save. Planning costs minutes of host time at
     RMAT22+ scale and is graph-deterministic, so every entry point (CLI,
     bench) should come through here. A failed save (read-only graph dir)
-    degrades to planning without a cache. ``pack`` is the caller's
-    nibble-packing intent (None = the LUX_PACK_STRIPS env default): a
-    cap-127 legacy cache is perfectly servable unless packing will
-    actually be used."""
+    degrades to planning without a cache. A cached plan with a count cap
+    other than ``cap`` (a legacy cap-127 cache) is served as it is."""
     import os
 
-    from lux_tpu.ops.tiled_spmv import load_plan, resolve_pack, save_plan
+    from lux_tpu.ops.tiled_spmv import load_plan, save_plan
 
     say = log if log is not None else (lambda *_: None)
     load_path = path
@@ -137,18 +130,6 @@ def get_cached_plan(
                 "— replanning"
             )
             plan = None
-        # A plan capped tighter than requested is servable (it just
-        # spilled a few more overflow edges to the tail). A looser cap
-        # only matters when nibble packing will actually be used — an
-        # unpacked run (the default and the measured-better config)
-        # serves cap-127 legacy plans as-is.
-        if plan is not None and plan.cap > cap and resolve_pack(pack, cap):
-            say(
-                f"cached plan {load_path} has count cap {plan.cap}, "
-                f"requested <= {cap} (nibble packing needs <= 15) "
-                "— replanning"
-            )
-            plan = None
         if plan is not None:
             return plan
     plan = plan_hybrid(graph, levels=levels, budget_bytes=budget_bytes, cap=cap)
@@ -192,7 +173,6 @@ class TiledPullExecutor:
         chunk_tail: int = DEFAULT_CHUNK_TAIL,
         plan: Optional[HybridPlan] = None,
         device=None,
-        pack: Optional[bool] = None,
     ):
         require_spmv_program(program, "TiledPullExecutor", "PullExecutor")
         self.graph = graph
@@ -208,24 +188,8 @@ class TiledPullExecutor:
         with spans.span("build.upload"):
             self.dhybrid = DeviceHybrid.build(
                 p, chunk_strips=chunk_strips, chunk_tail=chunk_tail,
-                device=device, pack=pack,
+                device=device,
             )
-            self.gtail = None
-            self.gtail_stats = None
-            if grouped_tail_enabled():
-                from lux_tpu.obs.metrics import counter, gauge
-                from lux_tpu.ops.merge_tail_plan import plan_grouped_tail
-
-                gplan = plan_grouped_tail(
-                    p.tail_sb, p.tail_lane, p.tail_row_ptr)
-                self.gtail = DeviceGroupedTail.build(gplan, device=device)
-                self.gtail_stats = gplan.stats
-                gauge("lux_grouped_tail_inflation").set(
-                    gplan.stats["mean_inflation"])
-                counter("lux_grouped_tail_copy_rows").inc(
-                    gplan.stats["copy_rows"])
-                counter("lux_grouped_tail_merge_rows").inc(
-                    gplan.stats["merge_rows"])
             self.out_degrees = put(p.out_degrees.astype(np.int32))
             self.in_degrees = put(p.in_degrees.astype(np.int32))
             self.order = put(p.order)   # external id at internal position
@@ -237,7 +201,6 @@ class TiledPullExecutor:
             self.dhybrid,
             self.out_degrees,
             self.in_degrees,
-            self.gtail,
         )
         self._jstep = jax.jit(self._step_impl, donate_argnums=0)
         self._step = lambda vals: self._jstep(vals, *self._step_args)
@@ -256,9 +219,9 @@ class TiledPullExecutor:
         return self.program.apply(vals, acc, ctx)
 
     def _step_impl(
-        self, vals, dhybrid, out_degrees, in_degrees, gtail=None
+        self, vals, dhybrid, out_degrees, in_degrees
     ) -> jnp.ndarray:
-        acc = hybrid_spmv(vals, dhybrid, gtail)
+        acc = hybrid_spmv(vals, dhybrid)
         with prof.region("lux.tiled.apply"):
             return self._apply_acc(vals, acc, out_degrees, in_degrees)
 
@@ -294,13 +257,7 @@ class TiledPullExecutor:
         sssp/sssp_gpu.cu:516-518 — phase names follow this engine's
         actual pipeline instead of the CUDA one). Returns
         (new external vals, {phase: seconds}). Phase dispatch breaks
-        XLA's cross-phase fusion, so the sum runs slower than step().
-
-        With the grouped tail active the tail phase is dispatched one
-        network level at a time; the per-level seconds land in
-        ``times["tail_level<k>"]`` and in the
-        ``lux_grouped_tail_level_seconds`` histograms (level 0 is the
-        x2d gather level), with ``times["tail"]`` still the total."""
+        XLA's cross-phase fusion, so the sum runs slower than step()."""
         from lux_tpu.ops.tiled_spmv import strips_sum, tail_sum, vals_to_x2d
 
         if not hasattr(self, "_jphase"):
@@ -327,54 +284,15 @@ class TiledPullExecutor:
         with Timer() as t:
             acc_s = hard_sync(strips_fn(internal, self.dhybrid))
         times["strips"] = t.elapsed
-        if self.gtail is not None:
-            acc_t = self._grouped_tail_phases(internal, times)
-        else:
-            with Timer() as t:
-                acc_t = hard_sync(tail_fn(internal, self.dhybrid))
-            times["tail"] = t.elapsed
+        with Timer() as t:
+            acc_t = hard_sync(tail_fn(internal, self.dhybrid))
+        times["tail"] = t.elapsed
         with Timer() as t:
             new = hard_sync(apply_fn(
                 internal, acc_s, acc_t, self.out_degrees, self.in_degrees
             ))
         times["apply"] = t.elapsed
         return self._to_external(new, self.rank), times
-
-    def _grouped_tail_phases(self, internal, times):
-        """Tail accumulator via the merge network, one hard-synced and
-        timed dispatch per level (plus the final masked per-dst
-        reduction). Composes the exact building blocks grouped
-        hybrid_spmv fuses, so attribution cannot drift from the real
-        step."""
-        from lux_tpu.obs.metrics import histogram
-        from lux_tpu.ops.merge_tail_kernel import level_apply, root_reduce
-        from lux_tpu.ops.tiled_spmv import vals_to_x2d
-
-        if not hasattr(self, "_jgphase"):
-            self._jgphase = (
-                jax.jit(vals_to_x2d), jax.jit(level_apply),
-                jax.jit(root_reduce),
-            )
-        x2d_fn, level_fn, finish_fn = self._jgphase
-        gt = self.gtail
-        total = 0.0
-        with Timer() as t:
-            x = hard_sync(x2d_fn(internal, self.dhybrid))
-        total += t.elapsed
-        for k in range(gt.n_levels + 1):
-            with Timer() as t:
-                x = hard_sync(level_fn(
-                    x, gt.arow[k], gt.brow[k], gt.codes[k]))
-            times[f"tail_level{k}"] = t.elapsed
-            histogram("lux_grouped_tail_level_seconds",
-                      {"level": str(k)}).observe(t.elapsed)
-            total += t.elapsed
-        with Timer() as t:
-            acc_t = hard_sync(finish_fn(
-                x, gt.nvalid_root, gt.dst_row_ptr))
-        total += t.elapsed
-        times["tail"] = total
-        return acc_t
 
     def warmup(self):
         """Compile the step and both permutation converters (run(1) with

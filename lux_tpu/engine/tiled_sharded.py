@@ -70,8 +70,6 @@ from lux_tpu.ops.tiled_spmv import (
     lane_select_tail_sums,
     plan_hybrid,
     round_chunk,
-    pack_strips,
-    resolve_pack,
     strip_level_spmv,
     zstream_boundaries,
 )
@@ -170,7 +168,6 @@ class ShardedLevel:
     xing_idx: jnp.ndarray   # (P, Xmax*r) int32
     xing_s0: jnp.ndarray    # (P, Xmax) int32
     xing_s1: jnp.ndarray    # (P, Xmax) int32
-    packed: bool = False    # nibble-packed strips (see pack_strips)
 
 
 @dataclasses.dataclass
@@ -191,7 +188,7 @@ for _cls, _data, _meta in (
     (ShardedLevel,
      ["strips", "cols", "bnd_row", "bnd_grp",
       "xing_idx", "xing_s0", "xing_s1"],
-     ["r", "segs", "packed"]),
+     ["r", "segs"]),
     (ShardedHybrid,
      ["levels", "tail_sb", "tail_lane", "tail_bnd_row", "tail_bnd_grp",
       "tail_xing_idx", "tail_xing_s0", "tail_xing_s1"],
@@ -254,7 +251,6 @@ class ShardedTiledExecutor:
         chunk_strips: int = DEFAULT_CHUNK_STRIPS,
         chunk_tail: int = DEFAULT_CHUNK_TAIL,
         plan: Optional[HybridPlan] = None,
-        pack=None,
     ):
         require_spmv_program(
             program, "ShardedTiledExecutor", "ShardedPullExecutor"
@@ -267,7 +263,6 @@ class ShardedTiledExecutor:
             graph, levels=levels, budget_bytes=budget_bytes
         )
         self.part = partition_plan(self.plan, self.num_parts)
-        self._pack = pack
         self._build_device_data(chunk_strips, chunk_tail)
 
         specs = {k: P(PARTS_AXIS) for k in self._shard_args}
@@ -348,17 +343,10 @@ class ShardedTiledExecutor:
                     xi, s0, s1 = crossing_correction(sub, lev.r)
                 xis.append(xi); s0s.append(s0); s1s.append(s1)
             xmax = max((a.shape[0] for a in s0s), default=0)
-            lev_packed = (
-                resolve_pack(self._pack, self.plan.cap) and lev.r % 2 == 0
-            )
-            rr = lev.r // 2 if lev_packed else lev.r
-            if lev_packed:
-                st = pack_strips(st)
             slevels.append(ShardedLevel(
                 r=lev.r,
                 segs=segs,
-                packed=lev_packed,
-                strips=put(st.reshape(pcount, kch, c, rr, BLOCK)),
+                strips=put(st.reshape(pcount, kch, c, lev.r, BLOCK)),
                 cols=put(co.reshape(pcount, kch, c)),
                 bnd_row=put(row),
                 bnd_grp=put(grp),
@@ -553,7 +541,6 @@ class ShardedTiledExecutor:
                 cols=lev.cols[0], bnd_row=lev.bnd_row[0],
                 bnd_grp=lev.bnd_grp[0], xing_idx=lev.xing_idx[0],
                 xing_s0=lev.xing_s0[0], xing_s1=lev.xing_s1[0],
-                packed=lev.packed,
             )
             acc_g = acc_g + strip_level_spmv(
                 x2d, dl, self.plan.nvb * (BLOCK // lev.r)

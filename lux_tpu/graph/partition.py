@@ -266,7 +266,7 @@ class ExchangePlan:
 
 # -- exchange-plan artifact (consumed by the jax-free exchange linter) -----
 #
-# Layout mirrors the grouped-tail plan artifact (plan.py / planck.py):
+# Layout, like the tiled plan cache (ops/tiled_spmv.save_plan):
 # one directory per plan, ``meta.json`` with the scalar fields plus one
 # ``.npy`` per table so the checker can mmap them without jax.
 # ``analysis/exchck.py`` mirrors these constants deliberately (it must

@@ -131,10 +131,8 @@ class HybridPlan:
     tail_row_ptr: np.ndarray  # (nv+1,) int64
     out_degrees: np.ndarray  # (nv,) int64, internal order
     in_degrees: np.ndarray   # (nv,) int64, internal order
-    # Per-cell count cap used at plan time (excess spilled to the tail).
-    # cap <= 15 makes every even-r level nibble-packable on device
-    # (two strip rows per int8 byte — see pack_strips); legacy plans
-    # used 127 and stay unpacked.
+    # Per-cell count cap used at plan time: a cell's parallel edges past
+    # it spill to the tail. Legacy plans used 127.
     cap: int = 15
     # Planning config, kept so plan caches can detect a changed request
     # (same r-cascade, different thresholds/budget). None/-1 on legacy
@@ -312,10 +310,9 @@ def plan_hybrid(
     ``levels`` is a sequence of ``(r, min_count)`` pairs, consumed in
     order: each level takes the strips (at granularity r x 128) holding
     at least ``min_count`` still-unassigned edges, densest first, within
-    what remains of ``budget_bytes`` (booked as unpacked int8 bytes).
+    what remains of ``budget_bytes`` (int8 strip bytes, r x 128 each).
     Cells holding more than ``cap`` parallel edges spill the excess to
-    the tail; cap <= 15 keeps every even-r level nibble-packable at
-    device-build time (opt-in, see DeviceHybrid.build).
+    the tail.
     """
     nv = graph.nv
     nvb = (nv + BLOCK - 1) // BLOCK
@@ -355,10 +352,7 @@ def plan_hybrid(
                 cols=np.zeros(0, np.int32),
             ))
             continue
-        # Budget books UNPACKED int8 bytes — nibble packing is an opt-in
-        # device-build decision (measured negative, see DeviceHybrid.build)
-        # the planner cannot assume; packed builds simply use less HBM
-        # than budgeted.
+        # The budget books each strip's device bytes: r x 128 int8 counts.
         strip_bytes = r * BLOCK
         if s is None:
             # Banded level 0: counts arrive prefiltered to >= min_count
@@ -559,7 +553,7 @@ def load_plan(path: str, mmap: bool = True) -> HybridPlan:
             levels=levels, tail_sb=z["tail_sb"], tail_lane=z["tail_lane"],
             tail_row_ptr=z["tail_row_ptr"],
             out_degrees=z["out_degrees"], in_degrees=z["in_degrees"],
-            cap=127,   # legacy .npz plans predate the nibble cap
+            cap=127,   # legacy .npz plans predate cap 15
         )
 
 
@@ -745,58 +739,21 @@ def strip_boundaries(rows: np.ndarray, nchunks: int, chunk: int, nrb: int,
 # ---------------------------------------------------------------------------
 
 
-def resolve_pack(pack, plan_cap: int):
-    """One shared gate for the nibble-packing decision: explicit ``pack``
-    wins, else the LUX_PACK_STRIPS env opt-in; packing also requires the
-    plan's count cap to fit a nibble. An explicit ``pack=True`` that the
-    plan cannot satisfy raises (mirroring PushExecutor's blocked_dense
-    validation) — only the env opt-in degrades silently. Per-level, r
-    must be even (checked at the call sites via ``r % 2 == 0``)."""
-    if pack is None:
-        from lux_tpu.utils import flags
-
-        pack = flags.get_bool("LUX_PACK_STRIPS")
-    elif pack and plan_cap > 15:
-        raise ValueError(
-            f"pack=True needs a plan with count cap <= 15 (got cap="
-            f"{plan_cap}, a legacy/unpacked plan) — replan with cap<=15"
-        )
-    return bool(pack) and plan_cap <= 15
-
-
-def pack_strips(strips: np.ndarray) -> np.ndarray:
-    """(..., r, 128) int8 counts <= 15 → (..., r/2, 128) uint8 nibbles.
-
-    Row j rides the low nibble, row j + r/2 the high nibble, so the
-    device-side unpack is one `& 15`, one `>> 4`, and a lane-axis concat
-    that lands in LOGICAL row order — no permutation anywhere. Halves
-    the per-iteration strip HBM traffic (the dominant strip-phase byte
-    stream); native int4 arrays would do the same but were not tried on
-    the chip."""
-    r = strips.shape[-2]
-    assert r % 2 == 0, "nibble packing needs an even strip height"
-    lo = strips[..., : r // 2, :].astype(np.uint8)
-    hi = strips[..., r // 2 :, :].astype(np.uint8)
-    return lo | (hi << 4)
-
-
 @dataclasses.dataclass
 class DeviceLevel:
     """One strip level on device, chunked for lax.scan (pad strips are
     zero-count → contribute nothing). Boundary fields are the static
-    Z-stream data from :func:`strip_boundaries`. ``packed`` marks
-    nibble-packed strips ((C, r/2, 128) uint8, see pack_strips)."""
+    Z-stream data from :func:`strip_boundaries`."""
 
     r: int
     segs: tuple             # static gather-table segmentation
-    strips: jnp.ndarray     # (nchunks, C, r, 128) int8 or packed uint8
+    strips: jnp.ndarray     # (nchunks, C, r, 128) int8
     cols: jnp.ndarray       # (nchunks, C) int32
     bnd_row: jnp.ndarray    # (nrb+1,) int32
     bnd_grp: jnp.ndarray    # (nrb+1,) int32
     xing_idx: jnp.ndarray   # (|X|*r,) int32 flat output positions
     xing_s0: jnp.ndarray    # (|X|,) int32
     xing_s1: jnp.ndarray    # (|X|,) int32
-    packed: bool = False
 
 
 @dataclasses.dataclass
@@ -818,18 +775,9 @@ class DeviceHybrid:
         chunk_strips: int = DEFAULT_CHUNK_STRIPS,
         chunk_tail: int = DEFAULT_CHUNK_TAIL,
         device=None,
-        pack=None,
     ) -> "DeviceHybrid":
-        """``pack=True`` nibble-packs even-r levels (needs plan.cap <= 15;
-        default: the LUX_PACK_STRIPS env knob via :func:`resolve_pack`).
-        MEASURED NEGATIVE on v5e (PERF_NOTES.md round 2): the strip scan is
-        VPU-bound, so halving its bytes buys nothing and the unpack adds
-        ~60% per-strip time (4.9 → 7.9 ns isolated, 114 → 139 ms/iter
-        end-to-end on RMAT22). Kept as an opt-in for hardware where the
-        balance differs."""
         put = lambda x: jax.device_put(jnp.asarray(x), device)
 
-        packed = resolve_pack(pack, plan.cap)
         dlevels = []
         for lev in plan.levels:
             nrb = plan.nvb * (BLOCK // lev.r)
@@ -846,15 +794,10 @@ class DeviceHybrid:
             row, grp, xi, s0, s1, segs = strip_boundaries(
                 lev.rows, k, c, nrb, lev.r
             )
-            lev_packed = packed and lev.r % 2 == 0
-            rr = lev.r // 2 if lev_packed else lev.r
-            if lev_packed:
-                st = pack_strips(st)
             dlevels.append(DeviceLevel(
                 r=lev.r,
                 segs=segs,
-                packed=lev_packed,
-                strips=put(st.reshape(k, c, rr, BLOCK)),
+                strips=put(st.reshape(k, c, lev.r, BLOCK)),
                 cols=put(co.reshape(k, c)),
                 bnd_row=put(row),
                 bnd_grp=put(grp),
@@ -979,18 +922,6 @@ def strip_level_spmv(x2d: jnp.ndarray, lev: DeviceLevel, nrb: int) -> jnp.ndarra
     def contrib_of(chunk):
         strips, cols = chunk
         xb = x2d[cols]                                  # (C, 128) row gather
-        if lev.packed:
-            # Nibble unpack: rows 0..r/2-1 in the low nibble, r/2..r-1
-            # in the high — the concat lands in logical row order.
-            lo = (strips & jnp.uint8(15)).astype(jnp.float32)
-            hi = (strips >> jnp.uint8(4)).astype(jnp.float32)
-            return jnp.concatenate(
-                [
-                    (lo * xb[:, None, :]).sum(-1),
-                    (hi * xb[:, None, :]).sum(-1),
-                ],
-                axis=-1,
-            )
         return (strips.astype(jnp.float32) * xb[:, None, :]).sum(-1)
 
     if r == BLOCK:
@@ -1142,22 +1073,11 @@ def tail_sum(x2d: jnp.ndarray, dh: DeviceHybrid) -> jnp.ndarray:
     )
 
 
-def hybrid_spmv(
-    vals: jnp.ndarray, dh: DeviceHybrid, gtail=None
-) -> jnp.ndarray:
+def hybrid_spmv(vals: jnp.ndarray, dh: DeviceHybrid) -> jnp.ndarray:
     """Full Σ vals[src] per destination over all layouts; (nv,) f32 in,
-    (nv,) f32 out (internal vertex order).
-
-    ``gtail`` (a :class:`~lux_tpu.ops.merge_tail_kernel.DeviceGroupedTail`)
-    swaps the lane-select tail for the grouped merge-network tail —
-    opt-in via LUX_GROUPED_TAIL=1 in the executors; both produce per-dst
-    sums of the same tail edge set."""
+    (nv,) f32 out (internal vertex order)."""
     nv = vals.shape[0]
     x2d = vals_to_x2d(vals, dh)
-    if gtail is not None:
-        from lux_tpu.ops.merge_tail_kernel import grouped_tail_sums
-
-        return strips_sum(x2d, dh, nv) + grouped_tail_sums(x2d, gtail)
     return strips_sum(x2d, dh, nv) + tail_sum(x2d, dh)
 
 
@@ -1165,7 +1085,7 @@ for _cls, _data, _meta in (
     (DeviceLevel,
      ["strips", "cols", "bnd_row", "bnd_grp",
       "xing_idx", "xing_s0", "xing_s1"],
-     ["r", "segs", "packed"]),
+     ["r", "segs"]),
     (DeviceHybrid,
      ["levels", "tail_sb", "tail_lane", "tail_bnd_row", "tail_bnd_grp",
       "tail_xing_idx", "tail_xing_s0", "tail_xing_s1"],

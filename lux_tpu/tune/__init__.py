@@ -2,8 +2,8 @@
 
 The repo measures everything (engobs phase splits, exchange-ledger
 useful_ratio, profile.v1 realized overlap, run-ledger config cohorts)
-but a human still picked ``LUX_EXCHANGE``, the GAS hysteresis, and the
-grouped tail by hand. This package closes the loop per
+but a human still picked ``LUX_EXCHANGE`` and the GAS hysteresis by
+hand. This package closes the loop per
 (graph fingerprint, program, engine kind, mesh shape, device kind):
 
 - :mod:`space` declares the knob axes the tuner may turn

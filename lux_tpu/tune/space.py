@@ -3,8 +3,8 @@
 The tuner may only turn knobs that are (a) declared in the flag
 registry, (b) captured at executor build time (so applying a winner at
 serving warmup never recompiles per query), and (c) bitwise-neutral for
-integral-valued programs — direction schedules, exchange packing, and
-tail plans all prove bitwise parity in their own gates. That set is
+integral-valued programs — direction schedules and exchange packing
+both prove bitwise parity in their own gates. That set is
 :data:`TUNER_MANAGED`; lux_doctor uses it to recognize "these two
 cohorts differ only by tuner-managed flags" and luxlint's LUX502 rejects
 any artifact that configures a flag outside it.
@@ -30,7 +30,7 @@ from typing import Dict, List, Optional
 from lux_tpu.utils import flags
 
 __all__ = ["TUNER_MANAGED", "knob_space", "default_candidate",
-           "is_sharded", "is_gas", "is_tiled"]
+           "is_sharded", "is_gas"]
 
 # Every flag the tuner is allowed to set. lux_doctor --tuned and
 # luxlint LUX502 both key off this set.
@@ -39,12 +39,10 @@ TUNER_MANAGED = frozenset({
     "LUX_EXCHANGE_FRONTIER_FRAC",
     "LUX_GAS_DENSITY_HI",
     "LUX_GAS_DENSITY_LO",
-    "LUX_GROUPED_TAIL",
 })
 
 _GAS_KINDS = frozenset({"gas", "gas_multi", "gas_sharded",
                         "gas_multi_sharded"})
-_TILED_KINDS = frozenset({"tiled", "tiled_sharded"})
 
 
 def is_sharded(engine_kind: str) -> bool:
@@ -53,10 +51,6 @@ def is_sharded(engine_kind: str) -> bool:
 
 def is_gas(engine_kind: str) -> bool:
     return engine_kind in _GAS_KINDS
-
-
-def is_tiled(engine_kind: str) -> bool:
-    return engine_kind in _TILED_KINDS
 
 
 def _sdef(name: str) -> str:
@@ -85,9 +79,6 @@ def _axes(engine_kind: str) -> List:
                      [_sdef("LUX_GAS_DENSITY_HI"), "0.25", "0.9"]))
         axes.append(("LUX_GAS_DENSITY_LO",
                      [_sdef("LUX_GAS_DENSITY_LO"), "0.05"]))
-    if is_tiled(engine_kind):
-        axes.append(("LUX_GROUPED_TAIL",
-                     [_sdef("LUX_GROUPED_TAIL"), "1"]))
     return axes
 
 

@@ -1,7 +1,7 @@
 """Central registry for every ``LUX_*`` environment flag.
 
-The knobs grew one module at a time (tiled_spmv, merge_tail_kernel, the
-obs layer, bench.py) until ~20 ``os.environ`` reads were scattered with
+The knobs grew one module at a time (tiled_spmv, the engines, the obs
+layer, bench.py) until ~20 ``os.environ`` reads were scattered with
 no single place to discover a flag's name, default, or meaning. This
 module is that place: every flag is :func:`define`'d here with a doc
 line, call sites read through the typed accessors, and luxlint's
@@ -275,8 +275,7 @@ define("LUX_NATIVE_CACHE", None,
        "native-library build cache dir (default ~/.cache/lux_tpu_native)",
        kind="path")
 
-# Engine / kernel knobs (engine/pull.py, ops/tiled_spmv.py,
-# ops/merge_tail_kernel.py)
+# Engine / kernel knobs (engine/pull.py, ops/tiled_spmv.py)
 define("LUX_EDGE_CHUNK_BYTES", 2 << 30,
        "flat-contribution byte threshold above which the pull engine "
        "runs edge-chunked", kind="int")
@@ -289,12 +288,6 @@ define("LUX_SRC_SLICE", None,
 define("LUX_PLAN_BANDED", None,
        "tiled planner level-0 banded passes: 1 force, 0 direct, unset "
        "auto by edge count", kind="tristate")
-define("LUX_PACK_STRIPS", False,
-       "opt-in nibble packing of even-r strip levels (needs plan count "
-       "cap <= 15)", kind="bool")
-define("LUX_GROUPED_TAIL", False,
-       "opt-in grouped (merge-network) tail phase in the tiled executors",
-       kind="bool")
 
 # GAS adaptive executor (engine/gas.py)
 define("LUX_GAS", "adaptive",
@@ -335,18 +328,13 @@ define("LUX_BENCH_GATE_TOL", 0.4,
        "per claim with --tol)",
        kind="float")
 
-# Static analysis, IR tier (analysis/ir.py, analysis/planck.py,
-# serve/pool.py)
+# Static analysis, IR tier (analysis/ir.py, serve/pool.py)
 define("LUX_IR_BLOWUP", 16.0,
        "luxlint-IR LUX103: flag any traced intermediate larger than this "
        "multiple of the step's total input bytes", kind="float")
 define("LUX_IR_POOL_AUDIT", True,
        "run the LUX104 donation audit on every engine the serve pool "
        "builds (one abstract lowering per build; 0 disables)", kind="bool")
-define("LUX_PLANCK_INFLATION", 8.0,
-       "luxlint-IR LUX205: max per-level grouped-tail stream inflation "
-       "(rows per level / ceil(reals/128)) a saved plan may carry",
-       kind="float")
 define("LUX_EXCH_POOL_AUDIT", True,
        "run the LUX401-403 exchange-plan audit on every plan-carrying "
        "engine the serve pool builds (pure numpy over the live "
@@ -529,12 +517,10 @@ define("LUX_TUNE_CACHE", 8,
        "(LRU; hot-swaps evict the outgoing fingerprint's entries "
        "regardless, like LUX_SHARD_PLAN_CACHE)", kind="int")
 
-# Smoke-tool knobs (tools/obs_smoke.py, serve_smoke.py, merge_smoke.py)
+# Smoke-tool knobs (tools/obs_smoke.py, serve_smoke.py)
 define("LUX_SMOKE_SCALE", 10, "smoke tools R-MAT scale", kind="int")
 define("LUX_SMOKE_ITERS", 8, "obs_smoke PageRank iterations", kind="int")
 define("LUX_SMOKE_QUERIES", 8, "serve_smoke SSSP query count", kind="int")
-define("LUX_SMOKE_EDGES", 1 << 20,
-       "merge_smoke heavy-tail synthetic edge count", kind="int")
 
 
 if __name__ == "__main__":

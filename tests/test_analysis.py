@@ -218,12 +218,12 @@ def test_flags_accessors(monkeypatch):
     monkeypatch.delenv("LUX_BENCH_SCALE", raising=False)
     assert flags.get_int("LUX_BENCH_SCALE") == 22
 
-    monkeypatch.delenv("LUX_PACK_STRIPS", raising=False)
-    assert flags.get_bool("LUX_PACK_STRIPS") is False
-    monkeypatch.setenv("LUX_PACK_STRIPS", "1")
-    assert flags.get_bool("LUX_PACK_STRIPS") is True
-    monkeypatch.setenv("LUX_PACK_STRIPS", "off")
-    assert flags.get_bool("LUX_PACK_STRIPS") is False
+    monkeypatch.delenv("LUX_LOCKWATCH", raising=False)
+    assert flags.get_bool("LUX_LOCKWATCH") is False
+    monkeypatch.setenv("LUX_LOCKWATCH", "1")
+    assert flags.get_bool("LUX_LOCKWATCH") is True
+    monkeypatch.setenv("LUX_LOCKWATCH", "off")
+    assert flags.get_bool("LUX_LOCKWATCH") is False
 
     monkeypatch.delenv("LUX_PLAN_BANDED", raising=False)
     assert flags.tristate("LUX_PLAN_BANDED") is None
@@ -394,7 +394,6 @@ def test_ir_flags_are_registered():
     # their use otherwise).
     assert flags.get_float("LUX_IR_BLOWUP") == 16.0
     assert flags.get_bool("LUX_IR_POOL_AUDIT") is True
-    assert flags.get_float("LUX_PLANCK_INFLATION") == 8.0
 
 
 def test_recompile_sentinel_thread_safe_under_concurrent_warmups():

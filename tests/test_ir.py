@@ -1,7 +1,6 @@
 """luxlint-IR: the jaxpr-tier rules (LUX101-105), the registry trace
-matrix, the grouped-plan artifact verifier (LUX201-205), the serve-pool
-donation-audit hook, and the CLI tiers (--ir / --plans / --changed /
---baseline).
+matrix, the serve-pool donation-audit hook, and the CLI tiers (--ir /
+--changed / --baseline).
 
 Seeded-violation convention (tests/ir_fixtures/): each ``lux1NN_*.py``
 module exposes ``TRACES`` and must make ``luxlint --ir`` exit 1 with
@@ -20,9 +19,8 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
-from lux_tpu.analysis import ir, planck  # noqa: E402
+from lux_tpu.analysis import ir  # noqa: E402
 from lux_tpu.models import ENGINE_KINDS  # noqa: E402
-from lux_tpu.ops import merge_tail_plan as mtp  # noqa: E402
 
 TESTS = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(TESTS)
@@ -173,119 +171,6 @@ def test_seeded_fixture_fires_exactly_its_rule(fixture, rule):
     assert report.summary()["errors"] == 0
 
 
-# -- grouped-plan verifier (planck) -------------------------------------
-
-
-@pytest.fixture(scope="module")
-def small_plan():
-    rng = np.random.default_rng(3)
-    sizes = np.minimum(
-        rng.lognormal(5.0, 1.2, size=48).astype(np.int64) + 1, 4000)
-    m = int(sizes.sum())
-    sb = np.repeat(np.arange(sizes.size), sizes)
-    rng.shuffle(sb)
-    lane = rng.integers(0, 128, size=m)
-    dst = np.sort(rng.integers(0, 64, size=m))
-    row_ptr = np.searchsorted(dst, np.arange(65))
-    return mtp.plan_grouped_tail(sb, lane, row_ptr)
-
-
-def _mutable(plan, **over):
-    """A SimpleNamespace copy of the plan with writable arrays."""
-    import types
-
-    d = {n: np.array(getattr(plan, n)) for n in planck.PLAN_ARRAYS}
-    d.update(n_edges=plan.n_edges, n_levels=plan.n_levels)
-    d.update(over)
-    return types.SimpleNamespace(**d)
-
-
-def test_planner_output_verifies_clean(small_plan):
-    res = planck.verify_plan(small_plan)
-    assert not res.findings and res.error is None
-
-
-def test_plan_contract_parity_with_ops():
-    # planck duplicates the artifact contract to stay jax-free; this is
-    # the drift tripwire the duplication comment promises.
-    assert planck.PLAN_ARRAYS == mtp.PLAN_ARRAYS
-    assert planck.PLAN_FORMAT == mtp.PLAN_FORMAT
-
-
-def test_plan_loader_roundtrip(tmp_path, small_plan):
-    path = str(tmp_path / "plan")
-    mtp.save_grouped_plan(path, small_plan)
-    loaded = planck.load_plan_artifact(path)
-    assert loaded.n_edges == small_plan.n_edges
-    assert loaded.n_levels == small_plan.n_levels
-    for name in planck.PLAN_ARRAYS:
-        np.testing.assert_array_equal(
-            np.asarray(getattr(loaded, name)),
-            np.asarray(getattr(small_plan, name)), err_msg=name)
-    assert not planck.verify_plan(loaded, path).findings
-
-
-def test_plan_structure_rejects_nonmonotone_level_ptr(small_plan):
-    lp = np.array(small_plan.level_ptr)
-    lp[2] = lp[1] - 1
-    res = planck.verify_plan(_mutable(small_plan, level_ptr=lp))
-    assert "LUX201" in {f.rule for f in res.findings}
-
-
-def test_plan_conservation_rejects_extra_real(small_plan):
-    nv = np.array(small_plan.nvalid)
-    idx = int(np.argmax(nv < planck.BLOCK))
-    assert nv[idx] < planck.BLOCK
-    nv[idx] += 1
-    res = planck.verify_plan(_mutable(small_plan, nvalid=nv))
-    assert {f.rule for f in res.findings} == {"LUX202"}
-
-
-def test_plan_code_plane_rejects_pad_garbage(small_plan):
-    codes = np.array(small_plan.codes)
-    nv = np.asarray(small_plan.nvalid)
-    idx = int(np.argmax(nv < planck.BLOCK))
-    codes[idx, -1] = 3
-    res = planck.verify_plan(_mutable(small_plan, codes=codes))
-    assert {f.rule for f in res.findings} == {"LUX203"}
-
-
-def test_plan_code_plane_rejects_wrong_side_lane(small_plan):
-    codes = np.array(small_plan.codes)
-    nv = np.asarray(small_plan.nvalid)
-    r0 = int(small_plan.level_ptr[1])
-    idx = int(np.argmax(nv[:r0] > 0))   # a live level-0 (copy-A) row
-    codes[idx, 0] = -5
-    res = planck.verify_plan(_mutable(small_plan, codes=codes))
-    assert {f.rule for f in res.findings} == {"LUX203"}
-
-
-def test_plan_code_plane_rejects_unknown_mode(small_plan):
-    mode = np.array(small_plan.mode)
-    mode[0] = 7
-    res = planck.verify_plan(_mutable(small_plan, mode=mode))
-    assert "LUX203" in {f.rule for f in res.findings}
-
-
-def test_plan_alignment_rejects_shifted_boundary(small_plan):
-    lp = np.array(small_plan.level_ptr)
-    lp[1] += 1   # still monotone: every level holds >= 8 rows
-    res = planck.verify_plan(_mutable(small_plan, level_ptr=lp))
-    assert "LUX204" in {f.rule for f in res.findings}
-
-
-def test_plan_copy_rate_bound_is_flag_tunable(small_plan, monkeypatch):
-    monkeypatch.setenv("LUX_PLANCK_INFLATION", "0.01")
-    res = planck.verify_plan(small_plan)
-    assert "LUX205" in {f.rule for f in res.findings}
-
-
-def test_unloadable_plan_dir_is_error(tmp_path):
-    report = planck.verify_plan_dirs([str(tmp_path / "nope")])
-    assert not report.ok
-    assert "unloadable" in report.results[0].error
-
-
 # -- serve-pool donation audit ------------------------------------------
 
 
@@ -372,21 +257,6 @@ def test_cli_ir_fixture_exits_nonzero():
                                          "lux104_donation.py"))
     assert proc.returncode == 1, proc.stdout + proc.stderr
     assert _summary_line(proc.stdout)["by_rule"] == {"LUX104": 1}
-
-
-def test_cli_plans_accepts_good_rejects_corrupt(tmp_path, small_plan):
-    good = str(tmp_path / "plan")
-    mtp.save_grouped_plan(good, small_plan)
-    proc = _run_cli("--plans", good)
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert _summary_line(proc.stdout)["schema"] == "luxlint.plan.v1"
-
-    lp = np.load(os.path.join(good, "level_ptr.npy"))
-    lp[2] = lp[1] - 1
-    np.save(os.path.join(good, "level_ptr.npy"), lp)
-    proc = _run_cli("--plans", good)
-    assert proc.returncode == 1
-    assert "LUX201" in proc.stdout
 
 
 def test_cli_baseline_masks_known_findings(tmp_path):

@@ -37,19 +37,20 @@ def plan_edge_multiset(plan):
     return sorted(edges)
 
 
+@pytest.mark.parametrize("cap", [15, 127])
 @pytest.mark.parametrize(
     "levels", [((8, 1),), ((8, 4),), ((128, 4), (8, 2)), ((32, 2),)]
 )
-def test_plan_is_exact_partition(levels):
+def test_plan_is_exact_partition(levels, cap):
     g = generate.rmat(9, 8, seed=3)
-    plan = plan_hybrid(g, levels=levels)
+    plan = plan_hybrid(g, levels=levels, cap=cap)
     s_int = plan.rank[g.col_src]
     d_int = plan.rank[g.col_dst]
     assert plan_edge_multiset(plan) == edge_multiset(s_int, d_int)
 
 
 def test_plan_spills_count_overflow_exactly():
-    # 300 parallel edges in one cell: count clips at the nibble cap (15),
+    # 300 parallel edges in one cell: count clips at the default cap (15),
     # the other 285 must reappear in the tail; with the legacy cap (127)
     # the clip point moves but the edge multiset is still exact.
     src = np.concatenate([np.full(300, 2), [0, 1, 3]])
@@ -63,46 +64,6 @@ def test_plan_spills_count_overflow_exactly():
         assert plan_edge_multiset(plan) == edge_multiset(s_int, d_int)
 
 
-def test_packed_strips_roundtrip_and_parity():
-    # Nibble packing must be lossless and the packed executor must match
-    # the plain engine bit-for-tolerance.
-    from lux_tpu.ops.tiled_spmv import pack_strips
-
-    rng = np.random.default_rng(0)
-    st = rng.integers(0, 16, (5, 8, 128)).astype(np.int8)
-    pk = pack_strips(st)
-    assert pk.shape == (5, 4, 128) and pk.dtype == np.uint8
-    np.testing.assert_array_equal(pk & 15, st[:, :4, :].astype(np.uint8))
-    np.testing.assert_array_equal(pk >> 4, st[:, 4:, :].astype(np.uint8))
-
-    from lux_tpu.engine.pull import PullExecutor
-
-    g = generate.rmat(10, 16, seed=4)
-    tex = TiledPullExecutor(
-        g, PageRank(), levels=((8, 1),), chunk_tail=64, pack=True
-    )
-    assert any(l.packed for l in tex.dhybrid.levels)
-    pex = PullExecutor(g, PageRank())
-    np.testing.assert_allclose(
-        np.asarray(tex.run(4)), np.asarray(pex.run(4)),
-        rtol=5e-5, atol=1e-9,
-    )
-
-
-def test_sharded_packed_parity():
-    from lux_tpu.engine.tiled_sharded import ShardedTiledExecutor
-    from lux_tpu.parallel.mesh import make_mesh
-
-    g = generate.rmat(10, 8, seed=6)
-    ex = ShardedTiledExecutor(
-        g, PageRank(), mesh=make_mesh(4), levels=((8, 1),),
-        chunk_strips=16, chunk_tail=64, pack=True,
-    )
-    got = np.asarray(ex.gather_values(ex.run(5)))
-    want = reference_pagerank(g, 5)
-    np.testing.assert_allclose(got, want, rtol=5e-5, atol=1e-9)
-
-
 def test_plan_rejects_unpackable_strip_heights():
     g = generate.rmat(9, 8, seed=3)
     for bad in (3, 48, 256):
@@ -112,9 +73,9 @@ def test_plan_rejects_unpackable_strip_heights():
 
 def test_plan_respects_budget_and_density_floor():
     g = generate.rmat(9, 8, seed=3)
-    # budget_bytes counts DEVICE bytes: packed strips cost r*128/2 each.
-    plan = plan_hybrid(g, levels=((8, 1),), budget_bytes=4 * 8 * BLOCK // 2)
-    assert plan.num_strips <= 4
+    # budget_bytes counts device bytes: r*128 int8 counts a strip.
+    plan = plan_hybrid(g, levels=((8, 1),), budget_bytes=2 * 8 * BLOCK)
+    assert plan.num_strips <= 2
     legacy = plan_hybrid(
         g, levels=((8, 1),), budget_bytes=4 * 8 * BLOCK, cap=127
     )
@@ -237,35 +198,75 @@ def test_plan_cache_detects_config_change(tmp_path):
     assert rebud.num_strips < rethr.num_strips
 
 
-def test_legacy_cap_served_unless_packing(tmp_path):
-    # A cap-127 cache is fully servable when nibble packing is off (the
-    # default); only a real packing request forces the replan (ADVICE r2
-    # medium). The replan must land at the ORIGINAL .luxplan path.
+def test_legacy_cap_plan_is_served(tmp_path):
+    # A cap-127 cache under the legacy key is served as it is, not
+    # replanned, and runs in parity with the reference. A replan (here
+    # for a changed threshold) lands at the ORIGINAL .luxplan path.
     import os
 
     from lux_tpu.engine.tiled import get_cached_plan
-    from lux_tpu.ops.tiled_spmv import plan_hybrid as ph, save_plan
+    from lux_tpu.ops.tiled_spmv import save_plan
 
     g = generate.rmat(9, 8, seed=3)
     legacy = str(tmp_path / "plan.npz")
-    save_plan(legacy + ".dir", ph(g, levels=((8, 2),), cap=127))
+    save_plan(legacy + ".dir", plan_hybrid(g, levels=((8, 2),), cap=127))
     os.rename(legacy + ".dir", legacy)   # simulate a legacy-keyed cache
     path = str(tmp_path / "plan.luxplan")
     served = get_cached_plan(g, path, levels=((8, 2),), cap=15)
     assert served.cap == 127             # served, not replanned
     assert not os.path.exists(path)
-    replanned = get_cached_plan(g, path, levels=((8, 2),), cap=15, pack=True)
-    assert replanned.cap <= 15
+    ex = TiledPullExecutor(g, PageRank(), plan=served, chunk_strips=16,
+                           chunk_tail=64)
+    np.testing.assert_allclose(
+        np.asarray(ex.run(5)), reference_pagerank(g, 5),
+        rtol=5e-5, atol=1e-9)
+    replanned = get_cached_plan(g, path, levels=((8, 4),), cap=15)
+    assert replanned.cap == 15
     assert os.path.exists(path)          # saved under the .luxplan name
 
 
-def test_explicit_pack_on_unpackable_plan_raises():
-    from lux_tpu.ops.tiled_spmv import DeviceHybrid
+def _cap_graph(name):
+    """A skewed or a local graph with parallel edges: 24 cells hold 40
+    copies and 6 hold 200, past caps 15, 31 and 127."""
+    g = (generate.rmat(10, 8, seed=8) if name == "rmat"
+         else generate.small_world(1000, k=12, seed=3))
+    rng = np.random.default_rng(9)
+    hot = rng.integers(0, g.nv, (30, 2))
+    reps = np.repeat([40, 200], [24, 6])
+    return Graph.from_edges(
+        np.concatenate([g.col_src, np.repeat(hot[:, 0], reps)]),
+        np.concatenate([g.col_dst, np.repeat(hot[:, 1], reps)]),
+        nv=g.nv)
 
-    g = generate.rmat(9, 8, seed=3)
-    plan = plan_hybrid(g, levels=((8, 2),), cap=127)
-    with pytest.raises(ValueError, match="cap"):
-        DeviceHybrid.build(plan, pack=True)
+
+@pytest.mark.parametrize("graph", ["rmat", "small_world"])
+@pytest.mark.parametrize("cap", [15, 31, 127])
+def test_hybrid_pagerank_parity_by_cap(cap, graph):
+    g = _cap_graph(graph)
+    plan = plan_hybrid(g, levels=((8, 2),), cap=cap)
+    assert max(int(lev.strips.max()) for lev in plan.levels) == cap
+    ex = TiledPullExecutor(g, PageRank(), plan=plan, chunk_strips=16,
+                           chunk_tail=128)
+    np.testing.assert_allclose(
+        np.asarray(ex.run(8)), reference_pagerank(g, 8),
+        rtol=5e-5, atol=1e-9)
+
+
+@pytest.mark.parametrize("cap", [15, 127])
+@pytest.mark.parametrize("parts", [1, 2, 4, 8])
+def test_sharded_pagerank_parity_by_cap(parts, cap):
+    from lux_tpu.engine.tiled_sharded import ShardedTiledExecutor
+    from lux_tpu.parallel.mesh import make_mesh
+
+    g = _cap_graph("rmat")
+    plan = plan_hybrid(g, levels=((8, 1),), cap=cap)
+    ex = ShardedTiledExecutor(
+        g, PageRank(), mesh=make_mesh(parts), plan=plan,
+        chunk_strips=16, chunk_tail=64,
+    )
+    got = np.asarray(ex.gather_values(ex.run(5)))
+    np.testing.assert_allclose(
+        got, reference_pagerank(g, 5), rtol=5e-5, atol=1e-9)
 
 
 def test_hybrid_run_resumes_from_external_vals():

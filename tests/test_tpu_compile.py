@@ -162,8 +162,8 @@ def test_tiled_step_reads_both_tables_from_vmem(one_chip, rmat14):
     dh = _scale22_hybrid(one_chip)
     vec = lambda dtype: jax.ShapeDtypeStruct((NV22,), dtype, sharding=one_chip)
     step = TiledPullExecutor(rmat14, PageRank())._jstep
-    text = step.lower(vec(jnp.float32), dh, vec(jnp.int32), vec(jnp.int32),
-                      None).compile().as_text()
+    text = step.lower(vec(jnp.float32), dh, vec(jnp.int32),
+                      vec(jnp.int32)).compile().as_text()
     tail = _table_reads(text, "lux.tiled.tail_gather", dh.nvb)
     strips = _table_reads(text, "lux.tiled.strip_scan", dh.nvb)
     assert tail and strips, (tail, strips)
@@ -194,44 +194,3 @@ def test_gas_adaptive_chunk_compiles(one_chip, rmat14):
     from lux_tpu.models.bfs import BFS
 
     _chunk_compiles(AdaptiveExecutor(rmat14, BFS()), one_chip, start=0)
-
-
-def _level_operands(rows, out_rows, seed=0):
-    import jax.numpy as jnp
-
-    rng = np.random.default_rng(seed)
-    x = jnp.asarray(rng.standard_normal((rows, 128)), jnp.float32)
-    arow = jnp.asarray(rng.integers(0, rows, out_rows), jnp.int32)
-    brow = jnp.asarray(rng.integers(0, rows, out_rows), jnp.int32)
-    codes = jnp.asarray(
-        rng.integers(-128, 128, (out_rows, 128)), jnp.int8)
-    return x, arow, brow, codes
-
-
-def test_merge_tail_level_kernel_compiles(one_chip):
-    import jax
-    import jax.numpy as jnp
-
-    from lux_tpu.ops.merge_tail_kernel import level_apply_pallas
-
-    rows, out_rows = 1 << 16, 100_000   # not a multiple of the row block
-    args = (
-        jax.ShapeDtypeStruct((rows, 128), jnp.float32, sharding=one_chip),
-        jax.ShapeDtypeStruct((out_rows,), jnp.int32, sharding=one_chip),
-        jax.ShapeDtypeStruct((out_rows,), jnp.int32, sharding=one_chip),
-        jax.ShapeDtypeStruct((out_rows, 128), jnp.int8, sharding=one_chip),
-    )
-    compiled = jax.jit(level_apply_pallas).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    _fits_one_chip(compiled)
-
-
-@pytest.mark.parametrize("out_rows", [32, 77])
-def test_merge_tail_level_kernel_matches_reference(out_rows):
-    from lux_tpu.ops.merge_tail_kernel import (
-        level_apply_pallas, level_apply_ref)
-
-    x, arow, brow, codes = _level_operands(50, out_rows)
-    got = level_apply_pallas(x, arow, brow, codes, interpret=True)
-    np.testing.assert_array_equal(
-        np.asarray(got), np.asarray(level_apply_ref(x, arow, brow, codes)))

@@ -19,8 +19,7 @@ Three claims, all asserted:
      and not merely passes.
 
 Exit status: 0 when all three hold. Emits one greppable
-``GASCKSMOKE {...}`` summary line (``gasck_smoke.v1``, the merge_smoke
-idiom).
+``GASCKSMOKE {...}`` summary line (``gasck_smoke.v1``).
 
 Usage:
     python tools/gasck_smoke.py               # default: 2s budget

@@ -11,8 +11,6 @@ Usage:
     python tools/luxlint.py --ir             # IR tier: trace every registered
                                              #   program x executor, run LUX1xx
     python tools/luxlint.py --ir fixture.py  # trace a module's TRACES list
-    python tools/luxlint.py --plans DIR...   # verify saved GroupedTailPlan
-                                             #   artifacts (LUX2xx, jax-free)
     python tools/luxlint.py --threads        # concurrency tier: lock
                                              #   discipline + lock-order graph
                                              #   (LUX3xx, stdlib AST)
@@ -40,8 +38,8 @@ Usage:
                                              #   absent from F fail the run
 
 Exit status: 0 clean, 1 unsuppressed findings or syntax/trace errors.
-Always emits one greppable summary line (`LUXLINT {...}`, the merge_smoke
-idiom) so CI logs carry the verdict even when output scrolls.
+Always emits one greppable summary line (`LUXLINT {...}`) so CI logs
+carry the verdict even when output scrolls.
 
 Suppress an AST-tier finding inline, with a reason:
     x.item()  # luxlint: disable=LUX001 -- intended once-per-run sync
@@ -191,15 +189,6 @@ def _run_exchange(paths, select: str):
     return ir.run_exchange_matrix(select=want)
 
 
-def _run_plans(paths, select: str):
-    from lux_tpu.analysis import planck
-    rules = planck.all_plan_rules()
-    if select:
-        want = {s.strip() for s in select.split(",") if s.strip()}
-        rules = [r for r in rules if r.id in want]
-    return planck.verify_plan_dirs(paths, rules)
-
-
 def _run_tune(paths, select: str):
     from lux_tpu.analysis import tuneck
     rules = tuneck.all_tune_rules()
@@ -257,8 +246,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="luxlint", description=__doc__)
     ap.add_argument("paths", nargs="*",
                     help=f"files/dirs to lint (default: {DEFAULT_TARGETS}); "
-                         "with --ir: fixture modules exporting TRACES; "
-                         "with --plans: saved plan artifact dirs")
+                         "with --ir: fixture modules exporting TRACES")
     ap.add_argument("--json", action="store_true",
                     help="emit the full report as JSON on stdout")
     ap.add_argument("--select", default="",
@@ -269,9 +257,6 @@ def main(argv=None) -> int:
                     help="run the jaxpr tier (LUX101-105) over every "
                          "registered program x executor, or over the TRACES "
                          "of the given fixture modules")
-    ap.add_argument("--plans", action="store_true",
-                    help="verify saved GroupedTailPlan artifact dirs "
-                         "(LUX201-205; jax-free, mmap load)")
     ap.add_argument("--threads", action="store_true",
                     help="run the concurrency tier (LUX301-305): thread-"
                          "shared state, lock-order graph, blocking-under-"
@@ -320,25 +305,18 @@ def main(argv=None) -> int:
                          "and pass; if present, fail only on new findings")
     args = ap.parse_args(argv)
 
-    if sum((args.ir, args.plans, args.threads, args.exchange,
+    if sum((args.ir, args.threads, args.exchange,
             args.tune, args.programs, args.memory)) > 1:
-        ap.error("--ir, --plans, --threads, --exchange, --tune, "
-                 "--programs, and --memory are separate tiers; run them "
-                 "separately")
+        ap.error("--ir, --threads, --exchange, --tune, --programs, and "
+                 "--memory are separate tiers; run them separately")
 
     if args.list_rules:
         for r in all_rules():
             print(f"{r.id}  {r.title}\n       {r.doc}")
         for r in all_thread_rules():
             print(f"{r.id}  {r.title}\n       {r.doc}")
-        # The IR/plan tiers import numpy/jax; keep --list-rules instant by
+        # The artifact tiers import numpy/jax; keep --list-rules instant by
         # documenting them from their modules only when importable cheaply.
-        try:
-            from lux_tpu.analysis import planck
-            for r in planck.all_plan_rules():
-                print(f"{r.id}  {r.title}\n       {r.doc}")
-        except Exception:
-            pass
         try:
             from lux_tpu.analysis import exchck
             for r in exchck.all_exchange_rules():
@@ -392,10 +370,6 @@ def main(argv=None) -> int:
                     sort_keys=True))
                 return 0
         report = _run_exchange(args.paths, args.select)
-    elif args.plans:
-        if not args.paths:
-            ap.error("--plans requires at least one artifact directory")
-        report = _run_plans(args.paths, args.select)
     elif args.tune:
         if not args.paths:
             ap.error("--tune requires at least one artifact file or "

@@ -24,8 +24,7 @@ Four claims, all asserted:
      eviction with zero recompiles on warm hits.
 
 Exit status: 0 when all four hold. Emits one greppable
-``MEMCKSMOKE {...}`` summary line (``memck_smoke.v1``, the merge_smoke
-idiom).
+``MEMCKSMOKE {...}`` summary line (``memck_smoke.v1``).
 
 Usage:
     python tools/memck_smoke.py               # default: 2s budget
